@@ -38,7 +38,7 @@ import jax.numpy as jnp
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 os.pardir, "src"))
-from roofline import HBM_BW, PEAK_FLOPS                     # noqa: E402
+from roofline import TARGET_KIND, chip_peaks               # noqa: E402
 from repro.kernels import autotune, ops, ref                # noqa: E402
 from repro.models.attention import (                        # noqa: E402
     decode_attention_ref, write_kv_cache)
@@ -48,6 +48,11 @@ from repro.parallel.decode_attn import (                    # noqa: E402
 REPO_ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          os.pardir)
 DEFAULT_OUT = os.path.join(REPO_ROOT, "BENCH_kernels.json")
+
+# the analytic terms price the tiles at the peaks of the chip the blocks
+# are tuned for
+PEAK_FLOPS = chip_peaks(TARGET_KIND)["bf16_flops"]
+HBM_BW = chip_peaks(TARGET_KIND)["hbm_bw"]
 
 # documented parity tolerances per dtype (max |kernel - ref| elementwise;
 # asserted in tests/test_kernels_autotune.py and re-gated per snapshot)

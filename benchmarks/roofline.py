@@ -2,9 +2,11 @@
 
 Per (arch x shape) cell on the single-pod mesh, derive:
 
-  compute term    = HLO_FLOPs / (chips * 197e12 bf16 FLOP/s)
-  memory term     = HLO_bytes / (chips * 819e9 B/s HBM)
-  collective term = collective_bytes / (chips * 50e9 B/s ICI link)
+  compute term    = HLO_FLOPs / (chips * peak bf16 FLOP/s)
+  memory term     = HLO_bytes / (chips * peak HBM B/s)
+  collective term = collective_bytes / (chips * ICI B/s per link)
+
+with the peaks of the dry-run's target chip read from ``CHIP_PEAKS``.
 
 ``cost_analysis`` counts lax.scan bodies once, so raw numbers from the full
 compile undercount by the trip count. The dry-run therefore lowers reduced
@@ -30,9 +32,27 @@ import math
 import os
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # B/s per chip
-LINK_BW = 50e9               # B/s per ICI link
+# Published peak rates of one chip, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip interconnect over 4 ICI links
+# (50 GB/s per link).
+CHIP_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9},
+}
+
+# the chip the dry-run meshes model (launch/mesh.py: v5e pods)
+TARGET_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> Dict[str, float]:
+    """Peak rates of ``device_kind``; a chip missing from the table is an
+    error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"add a sourced row to CHIP_PEAKS") from None
 
 COLL_KINDS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
               "collective-permute")
@@ -160,15 +180,16 @@ def analyse_cell(cell: Dict, chips: int = 256) -> Optional[Dict]:
     # cost_analysis reports PER-DEVICE numbers on the post-SPMD module
     # (verified: sharded fwd == global/nshards), so the per-chip roofline
     # terms divide only by per-chip peak rates:
-    t_compute = flops / PEAK_FLOPS
-    t_memory = hbytes / HBM_BW
-    t_coll = cbytes / LINK_BW
+    peak = chip_peaks(TARGET_KIND)
+    t_compute = flops / peak["bf16_flops"]
+    t_memory = hbytes / peak["hbm_bw"]
+    t_coll = cbytes / peak["ici_link_bw"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())
     mf = model_flops(cfg, shape)                  # global useful FLOPs
     mf_chip = mf / chips                          # per-chip useful FLOPs
-    ideal = mf_chip / PEAK_FLOPS
+    ideal = mf_chip / peak["bf16_flops"]
     roofline_frac = ideal / bound if bound > 0 else 0.0
 
     suggestions = {

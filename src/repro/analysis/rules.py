@@ -38,7 +38,7 @@ def terminal_name(node: ast.AST) -> Optional[str]:
 
 # ---------------------------------------------------------------------------
 # compat-drift — ROADMAP: "New sharding/mesh code must import from
-# repro.compat, not raw jax names" (jax 0.4.37 vs 0.6+ bridge, PR 3)
+# repro.compat, not raw jax names" (one import surface, PR 3)
 # ---------------------------------------------------------------------------
 
 @register
@@ -46,17 +46,17 @@ class CompatDriftRule(Rule):
     name = "compat-drift"
     description = ("sharding/mesh/cost_analysis surfaces must go through "
                    "repro.compat, not raw jax.sharding/jax.experimental "
-                   "names (jax 0.4.x vs 0.6+ bridge)")
+                   "names (one import surface for jax's sharding API)")
     include = ("src/repro/",)
     exclude = ("src/repro/compat.py", "src/repro/analysis/")
 
-    # module prefixes that are version-bridged: importing them raw scatters
-    # version checks the bridge exists to centralize
+    # module prefixes that repro.compat fronts: importing them raw scatters
+    # the conventions compat.py keeps in one place
     BRIDGED_MODULES = ("jax.sharding", "jax.experimental")
-    # top-level jax names whose signature/semantics moved across versions
+    # top-level jax names that repro.compat wraps
     BRIDGED_NAMES = {"jax.set_mesh", "jax.shard_map", "jax.make_mesh"}
-    # intentionally-raw allowlist: Pallas is a kernel-only surface with no
-    # 0.4/0.6 bridge, so kernels import it directly
+    # intentionally-raw allowlist: Pallas is a kernel-only surface that
+    # compat.py does not front, so kernels import it directly
     PALLAS_DIRS = ("src/repro/kernels/",)
     PALLAS_PREFIX = "jax.experimental.pallas"
 
@@ -87,7 +87,7 @@ class CompatDriftRule(Rule):
                         out.append(rule.violation(
                             path, node,
                             f"import from {mod!r}: use the repro.compat "
-                            f"re-export instead (jax 0.4/0.6 bridge)"))
+                            f"re-export instead"))
                 elif mod == "jax":
                     for alias in node.names:
                         full = f"jax.{alias.name}"
@@ -126,7 +126,7 @@ class CompatDriftRule(Rule):
                 elif chain in rule.BRIDGED_NAMES:
                     out.append(rule.violation(
                         path, node,
-                        f"{chain}: bridged across jax versions — call "
+                        f"{chain}: wrapped by repro.compat — call "
                         f"repro.compat.{chain.split('.', 1)[1]} instead"))
                 # never descend: the inner Attributes are this same chain
 
@@ -138,8 +138,7 @@ class CompatDriftRule(Rule):
                             and owner.id == "compat"):
                         out.append(rule.violation(
                             path, node,
-                            "raw .cost_analysis() returned a per-device "
-                            "list on jax 0.4.x — use "
+                            "raw .cost_analysis() may return None — use "
                             "compat.cost_analysis(compiled)"))
                 self.generic_visit(node)
 

@@ -1,76 +1,47 @@
-"""Version-bridging wrappers for jax APIs that moved between 0.4.x and 0.6+.
+"""The single import surface for jax's sharding and mesh API.
 
-The sharding stack targets the modern sharding-in-types surface
-(``jax.set_mesh`` / ``jax.shard_map`` / ``jax.sharding.get_abstract_mesh`` /
-``AxisType``); on a 0.4.x container those names don't exist but the legacy
-equivalents (Mesh-as-context-manager, ``jax.experimental.shard_map``,
-thread-resources physical mesh) behave identically for our usage. Every
-wrapper prefers the modern name and falls back, so the same code runs on
-both without scattering version checks through models/parallel/launch.
+Sharding, mesh and cost-analysis code imports these names from here, never
+from ``jax.sharding`` / ``jax.experimental`` directly (the compat-drift
+analysis rule pins this), so a future move of any of them is one edit in
+this file. The wrappers fix the conventions the repo relies on: meshes
+with explicit ``Auto`` axis types, an ambient mesh that reads as None when
+absent, and cost analysis as a plain dict.
 """
 from __future__ import annotations
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-# Stable sharding types, re-exported so sharding code has a single import
-# surface (the compat-drift analysis rule pins this): these names exist
-# unchanged in 0.4.x and 0.6+, while the functions below need real bridging.
 P = PartitionSpec
 
 __all__ = ["Mesh", "NamedSharding", "PartitionSpec", "P", "make_mesh",
            "set_mesh", "get_abstract_mesh", "cost_analysis", "shard_map"]
 
 
-def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where they exist."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """jax.make_mesh with every axis ``Auto`` (sharding propagated by XLA),
+    over ``devices`` when given, else over all devices."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    Modern jax: ``jax.set_mesh``. Legacy jax: ``Mesh`` is itself a context
-    manager that enters the resource env (enabling bare-PartitionSpec
-    ``with_sharding_constraint`` under jit), so the mesh doubles as the cm.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def get_abstract_mesh():
     """The ambient mesh, or None when no mesh context is active."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        m = getter()
-        return m if m is not None and m.axis_names else None
-    from jax._src import mesh as mesh_lib
-    m = mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    m = jax.sharding.get_abstract_mesh()
+    return m if m is not None and m.axis_names else None
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a dict: 0.4.x returned a per-device
-    list of dicts, modern jax returns the dict directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict (empty when unavailable)."""
+    return compiled.cost_analysis() or {}
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map on modern jax, jax.experimental.shard_map below it
-    (where the replication check is spelled ``check_rep``)."""
-    modern = getattr(jax, "shard_map", None)
-    if modern is not None:
-        return modern(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)

@@ -24,10 +24,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 _LANES = 128
 
-# modern jax renamed TPUCompilerParams -> CompilerParams; support both
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             scale: float, causal: bool, block_q: int, block_k: int,
@@ -117,7 +113,7 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # running denom
             pltpu.VMEM((block_q, D), jnp.float32),        # output accumulator
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -7,7 +7,6 @@ calls these through RunFlags.backend.
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -38,16 +37,13 @@ def _flash_bhsd(q, k, v, causal: bool, interpret: bool, block_q: int,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
-                    lengths: Optional[jax.Array] = None,
                     interpret: bool = False) -> jax.Array:
     """q,k,v: (B,S,H,D) (kv pre-repeated to H heads). Returns (B,S,H,D).
 
-    Ragged ``lengths`` masking falls back to the XLA online-softmax path
-    (the kernel handles the dense causal/full cases the dry-run shapes use).
+    Dense causal/full attention only: the kernel has no ragged-``lengths``
+    mask, so ``models.attention.self_attention`` sends ragged calls to the
+    XLA path itself.
     """
-    if lengths is not None:
-        from repro.models.attention import flash_attention_xla
-        return flash_attention_xla(q, k, v, causal=causal, lengths=lengths)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -56,9 +52,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return o.transpose(0, 2, 1, 3)
 
 
-def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-5,
-            backend: str = "interpret") -> jax.Array:
-    """x: (..., D). Fused RMSNorm."""
+def rmsnorm(x: jax.Array, w: jax.Array, *, backend: str,
+            eps: float = 1e-5) -> jax.Array:
+    """x: (..., D). Fused RMSNorm. ``backend``: pallas | interpret | xla."""
     if backend == "xla":
         return _ref.rmsnorm_ref(x, w, eps=eps)
     shape = x.shape
@@ -70,7 +66,7 @@ def rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-5,
 
 
 def rmsnorm_residual(x: jax.Array, residual: jax.Array, w: jax.Array, *,
-                     eps: float = 1e-5, backend: str = "interpret"):
+                     backend: str, eps: float = 1e-5):
     if backend == "xla":
         return _ref.rmsnorm_residual_ref(x, residual, w, eps=eps)
     shape = x.shape

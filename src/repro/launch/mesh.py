@@ -22,6 +22,17 @@ def make_tiny_mesh(*, multi_pod: bool = False):
     return _mk(shape, axes)
 
 
-def make_local_mesh():
-    """Single-device mesh with the production axis names (smoke tests)."""
-    return _mk((1, 1), ("data", "model"))
+def local_mesh_shape(n: int):
+    """(data, model) for ``n`` devices: the model axis is the largest divisor
+    of ``n`` that is at most its square root — one chip (1, 1), a 2x2 host
+    (2, 2), eight devices (4, 2)."""
+    model = max(d for d in range(1, int(n ** 0.5) + 1) if n % d == 0)
+    return n // model, model
+
+
+def make_local_mesh(devices=None):
+    """(data, model) mesh over ``devices`` (default: every local device)."""
+    import jax
+    devices = list(jax.local_devices() if devices is None else devices)
+    return _mk(local_mesh_shape(len(devices)), ("data", "model"),
+               devices=devices)

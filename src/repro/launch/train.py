@@ -1,35 +1,131 @@
 """Fleet training driver.
 
 On a real TPU fleet each host runs this under its own process with
-``jax.distributed.initialize()``; on this harness it runs the same code on
-the local device (or a forced-device tiny mesh via REPRO_DRYRUN_DEVICES).
-Platform knobs (v5e collective-overlap XLA flags, REPRO_PLATFORM /
-REPRO_X64 / REPRO_HOST_DEVICES) come from ``repro.runtime``.
+``jax.distributed.initialize()``; here it runs the same code over the local
+devices (``--mesh local``: every chip of the host) or a forced-device tiny
+mesh. Platform knobs (REPRO_PLATFORM / REPRO_X64 / REPRO_HOST_DEVICES) and
+the persistent compilation cache come from ``repro.runtime``.
 
   PYTHONPATH=src python -m repro.launch.train --arch tacc-100m --smoke \
       --steps 100 --global-batch 8 --seq-len 64 --ckpt-dir /tmp/run1
+
+:func:`train` is the whole loop; ``main`` and ``chip_smoke.py`` both call it.
 """
-from repro import runtime
-
-# before the first jax import: device count / platform / XLA flags lock in
-# at backend init
-runtime.init_from_env()
-
 import argparse
 import time
+from dataclasses import dataclass, field
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 
-from repro import compat
+from repro import compat, runtime
 from repro.compat import NamedSharding, P
 from repro.ckpt import Checkpointer, latest_step
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.data import SyntheticLM
-from repro.models import model_defs, param_shardings
 from repro.models.transformer import RunFlags
 from repro.train import (OptConfig, TrainConfig, build_train_step,
                          init_train_state)
+from repro.train.step import state_shardings
+
+
+def sharded_train_step(cfg: ModelConfig, ocfg: OptConfig, tcfg: TrainConfig,
+                       mesh):
+    """The jitted train step for ``mesh`` (state donated), with the state
+    and batch shardings it takes."""
+    b_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    flags = RunFlags(distributed=True, token_axes=b_axes)
+    st_sh = state_shardings(cfg, mesh)
+    b_sh = NamedSharding(mesh, P(b_axes))      # every batch leaf: rows split
+    step_fn = jax.jit(build_train_step(cfg, ocfg, tcfg, flags),
+                      in_shardings=(st_sh, b_sh), out_shardings=(st_sh, None),
+                      donate_argnums=0)
+    return step_fn, st_sh, b_sh
+
+
+@dataclass
+class TrainResult:
+    """What one :func:`train` call ran, and the compiled step to run more."""
+    state: Any                       # final state, placed on the mesh
+    step_fn: Callable                # compiled: (state, batch) -> (state, m)
+    batch: Callable[[int], Any]      # step -> batch placed on the mesh
+    state_shardings: Any
+    compile_s: float
+    first_step_s: float              # first call, block_until_ready
+    steady_step_s: Optional[float]   # mean of the later steps
+    tokens_per_step: int
+    metrics: List[Dict[str, float]] = field(default_factory=list)
+
+
+def train(cfg: ModelConfig, mesh, *, steps: int, global_batch: int,
+          seq_len: int, lr: float = 1e-3, seed: int = 0,
+          microbatches: int = 1, ckpt_dir: Optional[str] = None,
+          ckpt_every: int = 50, log_every: int = 10,
+          log: Callable[[str], None] = print) -> TrainResult:
+    """Train ``cfg`` on synthetic data over ``mesh`` up to step ``steps``.
+
+    Resumes from the latest checkpoint in ``ckpt_dir`` when there is one,
+    saves every ``ckpt_every`` steps and at the end. The step is compiled
+    ahead of the loop, so ``compile_s`` is compilation alone; step times
+    end in ``block_until_ready``."""
+    ocfg = OptConfig(lr=lr, warmup_steps=max(steps // 10, 1),
+                     total_steps=steps)
+    tcfg = TrainConfig(n_microbatches=microbatches)
+    step_fn, st_sh, b_sh = sharded_train_step(cfg, ocfg, tcfg, mesh)
+    data = SyntheticLM(cfg, global_batch, seq_len, seed=seed,
+                       host_id=jax.process_index(),
+                       n_hosts=jax.process_count())
+    ck = Checkpointer(ckpt_dir, keep=3) if ckpt_dir else None
+
+    def batch(i: int):
+        return {k: jax.make_array_from_process_local_data(b_sh, v)
+                for k, v in data.batch(i).items()}
+
+    with compat.set_mesh(mesh):
+        start = 0
+        if ck and latest_step(ckpt_dir) is not None:
+            state, man = ck.restore(shardings=st_sh)
+            start = man["step"]
+            log(f"restored step {start}")
+        else:
+            state = init_train_state(cfg, ocfg, jax.random.PRNGKey(seed))
+            state = jax.device_put(state, st_sh)
+        t0 = time.perf_counter()
+        compiled = step_fn.lower(state, batch(start)).compile()
+        compile_s = time.perf_counter() - t0
+
+        tok = global_batch * seq_len
+        metrics: List[Dict[str, float]] = []
+        first_s, t_steady = 0.0, time.perf_counter()
+        last_log, t_log = start, t_steady
+        for i in range(start, steps):
+            state, m = compiled(state, batch(i))
+            metrics.append(m)
+            if i == start:
+                jax.block_until_ready(state)
+                t_steady = t_log = time.perf_counter()
+                first_s = t_steady - t0 - compile_s
+            elif (i + 1) % log_every == 0 or i + 1 == steps:
+                loss = float(m["loss"])          # waits for step i
+                dt = time.perf_counter() - t_log
+                log(f"step {int(m['step']):5d} loss {loss:.4f} "
+                    f"gnorm {float(m['grad_norm']):.3f} "
+                    f"lr {float(m['lr']):.2e} "
+                    f"tok/s {(i - last_log) * tok / dt:,.0f}")
+                last_log, t_log = i, time.perf_counter()
+            if ck and (i + 1) % ckpt_every == 0 and i + 1 < steps:
+                ck.save(i + 1, state)
+        jax.block_until_ready(state)
+        steady = None
+        if steps - start > 1:
+            steady = (time.perf_counter() - t_steady) / (steps - start - 1)
+        if ck:
+            ck.save(steps, state, block=True)
+    metrics = [{k: float(v) for k, v in m.items()}
+               for m in jax.device_get(metrics)]
+    return TrainResult(state, compiled, batch, st_sh, compile_s, first_s,
+                       steady, tok, metrics)
 
 
 def main() -> None:
@@ -47,6 +143,9 @@ def main() -> None:
     ap.add_argument("--mesh", choices=["local", "tiny", "pod", "multipod"],
                     default="local")
     args = ap.parse_args()
+    # before the first jax computation: device count / platform lock in at
+    # backend init, and the compile cache at the first compilation
+    runtime.init_from_env()
 
     from repro.launch.mesh import (make_local_mesh, make_production_mesh,
                                    make_tiny_mesh)
@@ -54,49 +153,12 @@ def main() -> None:
             "pod": make_production_mesh,
             "multipod": lambda: make_production_mesh(multi_pod=True)}[
         args.mesh]()
-
-    cfg = get_config(args.arch, smoke=args.smoke)
-    ocfg = OptConfig(lr=args.lr, warmup_steps=max(args.steps // 10, 1),
-                     total_steps=args.steps)
-    tcfg = TrainConfig(n_microbatches=args.microbatches)
-    b_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    flags = RunFlags(distributed=True, token_axes=b_axes)
-    pshard = param_shardings(model_defs(cfg), mesh)
-    scalar = NamedSharding(mesh, P())
-    st_sh = {"params": pshard, "opt": {"m": pshard, "v": pshard,
-                                       "step": scalar}}
-    step_fn = jax.jit(build_train_step(cfg, ocfg, tcfg, flags),
-                      in_shardings=(st_sh, None), out_shardings=(st_sh, None),
-                      donate_argnums=0)
-    data = SyntheticLM(cfg, args.global_batch, args.seq_len, seed=args.seed,
-                       host_id=jax.process_index(),
-                       n_hosts=jax.process_count())
-    ck = Checkpointer(args.ckpt_dir, keep=3) if args.ckpt_dir else None
-
-    with compat.set_mesh(mesh):
-        start = 0
-        if ck and latest_step(args.ckpt_dir) is not None:
-            state, man = ck.restore(shardings=st_sh)
-            start = man["step"]
-            print(f"restored step {start}")
-        else:
-            state = init_train_state(cfg, ocfg, jax.random.PRNGKey(args.seed))
-            state = jax.device_put(state, st_sh)
-        t0 = time.time()
-        for i in range(start, args.steps):
-            batch = {k: jnp.asarray(v) for k, v in data.batch(i).items()}
-            state, m = step_fn(state, batch)
-            if (i + 1) % 10 == 0 or i + 1 == args.steps:
-                dt = time.time() - t0
-                tok = 10 * args.global_batch * args.seq_len
-                print(f"step {int(m['step']):5d} loss {float(m['loss']):.4f} "
-                      f"gnorm {float(m['grad_norm']):.3f} "
-                      f"lr {float(m['lr']):.2e} tok/s {tok/max(dt,1e-9):,.0f}")
-                t0 = time.time()
-            if ck and (i + 1) % args.ckpt_every == 0:
-                ck.save(i + 1, state)
-        if ck:
-            ck.save(args.steps, state, block=True)
+    r = train(get_config(args.arch, smoke=args.smoke), mesh,
+              steps=args.steps, global_batch=args.global_batch,
+              seq_len=args.seq_len, lr=args.lr, seed=args.seed,
+              microbatches=args.microbatches, ckpt_dir=args.ckpt_dir,
+              ckpt_every=args.ckpt_every)
+    print(f"compile {r.compile_s:.1f}s, first step {r.first_step_s:.3f}s")
     print("done")
 
 

@@ -195,9 +195,11 @@ def self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
     q, k, v = project_qkv(cfg, p, x, positions)
     kf = repeat_kv(k, cfg.q_per_kv)
     vf = repeat_kv(v, cfg.q_per_kv)
-    if backend in ("pallas", "interpret"):
+    # the Pallas kernel has no ragged-length mask: ragged calls (every serve
+    # prefill) take the XLA path on every backend
+    if backend in ("pallas", "interpret") and lengths is None:
         from repro.kernels import ops as kops
-        o = kops.flash_attention(q, kf, vf, causal=True, lengths=lengths,
+        o = kops.flash_attention(q, kf, vf, causal=True,
                                  interpret=(backend == "interpret"))
     else:
         o = flash_attention_xla(q, kf, vf, causal=True, lengths=lengths,

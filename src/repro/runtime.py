@@ -26,6 +26,11 @@ import warnings
 from multiprocessing import cpu_count
 from typing import Dict, Optional
 
+# <checkout>/.jax_cache: this file is <checkout>/src/repro/runtime.py
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
+
 # well-known tcmalloc locations, most specific first (HomebrewNLP's run.sh
 # preloads the Debian/Ubuntu path; conda ships its own)
 _TCMALLOC_PATHS = (
@@ -78,47 +83,36 @@ def ensure_platform_env(platform: str = "cpu",
     tgt.setdefault("JAX_PLATFORMS", platform)
 
 
-# v5e collective-overlap flag set (async collective fusion + compute/ICI
-# overlap): the standard fleet-training XLA tuning, applied on TPU hosts
-# that have not hand-tuned XLA_FLAGS themselves
-_TPU_PERF_FLAGS = (
-    "--xla_tpu_enable_data_parallel_all_reduce_opt=true",
-    "--xla_tpu_data_parallel_opt_different_sized_ops=true",
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    "--xla_tpu_overlap_compute_collective_tc=true",
-)
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise the cache lives
+    at the fixed :data:`DEFAULT_COMPILE_CACHE`, so a second run from the
+    same checkout finds what the first one compiled (a directory that moves
+    between runs never hits).  Call before the first compilation: JAX
+    decides once, at its first compile, whether the cache is in use."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_COMPILE_CACHE
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
-def apply_tpu_perf_flags(env: Optional[Dict[str, str]] = None) -> bool:
-    """Apply the v5e collective-overlap flags on a TPU fleet host
-    (``TPU_NAME`` set) unless ``XLA_FLAGS`` was already hand-tuned.
-    Returns True when the flags were applied."""
-    tgt = os.environ if env is None else env
-    if "TPU_NAME" not in tgt or "XLA_FLAGS" in tgt:
-        return False
-    flags = ""
-    for f in _TPU_PERF_FLAGS:
-        flags = _merge_xla_flag(flags, f)
-    tgt["XLA_FLAGS"] = flags
-    return True
-
-
-def init_from_env() -> None:
-    """Entrypoint hook for ``launch/`` mains: apply the env-driven platform
-    knobs (``REPRO_HOST_DEVICES``, ``REPRO_PLATFORM``, ``REPRO_X64``) plus
-    the TPU perf flags.  Must run before the first jax computation; pure
+def init_from_env() -> str:
+    """Entrypoint hook for ``launch/`` mains and ``chip_smoke.py``: apply the
+    env-driven platform knobs (``REPRO_HOST_DEVICES``, ``REPRO_PLATFORM``,
+    ``REPRO_X64``) and turn on the persistent compilation cache, whose
+    directory it returns.  Must run before the first jax computation; pure
     env-var work happens first so the jax-touching knobs see it."""
     n = os.environ.get("REPRO_HOST_DEVICES")
     if n:
         force_host_device_count(int(n))
-    apply_tpu_perf_flags()
     platform = os.environ.get("REPRO_PLATFORM")
     if platform:
         set_platform(platform)
     x64 = os.environ.get("REPRO_X64")
     if x64 is not None:
         enable_x64(x64.lower() not in ("", "0", "false"))
+    return enable_compile_cache()
 
 
 def set_platform(platform: str = "cpu") -> None:

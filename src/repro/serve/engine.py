@@ -11,6 +11,7 @@ inference tasks.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -29,6 +30,12 @@ class GenerationResult:
     prompt: List[int]
     tokens: List[int] = field(default_factory=list)
     done: bool = False
+    # host perf_counter stamps, the last two taken once the device produced
+    # the token: arrival (admission, or the start of ``run`` for the
+    # requests it queues), first token, last token
+    arrived_s: float = 0.0
+    first_token_s: float = 0.0
+    finished_s: float = 0.0
 
 
 @dataclass
@@ -76,6 +83,7 @@ class ServeEngine:
         slot = self._free_slot()
         if slot is None:
             return None
+        arrived_s = time.perf_counter()
         prompt = list(prompt)[: self.max_seq - max_new - 1]
         toks = np.zeros((1, self.max_seq), np.int32)
         toks[0, :len(prompt)] = prompt
@@ -83,10 +91,11 @@ class ServeEngine:
         logits, row_cache = self._prefill1(
             self.params, {"tokens": jnp.asarray(toks)}, lengths)
         self._splice(slot, row_cache)
-        req = GenerationResult(self._next_id, prompt)
+        req = GenerationResult(self._next_id, prompt, arrived_s=arrived_s)
         self._next_id += 1
         first = self._pick(np.asarray(logits)[0])
         req.tokens.append(int(first))
+        req.first_token_s = time.perf_counter()
         self._slots[slot] = _Slot(req, max_new - 1, int(first))
         return req
 
@@ -137,6 +146,7 @@ class ServeEngine:
         self.cache["lengths"] = jnp.where(jnp.asarray(occupied),
                                           self.cache["lengths"], prev_lengths)
         logits = np.asarray(logits)
+        now = time.perf_counter()
         finished = []
         self._steps += 1
         for i, s in enumerate(self._slots):
@@ -149,6 +159,7 @@ class ServeEngine:
             hit_eos = self.eos_id is not None and nxt == self.eos_id
             if s.remaining <= 0 or hit_eos:
                 s.request.done = True
+                s.request.finished_s = now
                 finished.append(s.request)
                 self._slots[i] = _Slot()
                 self.cache["lengths"] = self.cache["lengths"].at[i].set(0)
@@ -159,11 +170,13 @@ class ServeEngine:
         """Serve a workload of prompts to completion (continuous batching)."""
         queue = list(requests)
         results: List[GenerationResult] = []
+        t0 = time.perf_counter()
         while queue or self.active():
             while queue:
                 r = self.add_request(queue[0], max_new=max_new)
                 if r is None:
                     break
+                r.arrived_s = t0             # waited in this queue since t0
                 results.append(r)
                 queue.pop(0)
             if self.active():

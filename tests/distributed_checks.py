@@ -245,6 +245,16 @@ def check_tiny_dryrun():
         print("tiny dryrun ok", arch, shape, rec["full"]["flops"])
 
 
+def check_chip_smoke_sharded():
+    """``chip_smoke.py --chips 4``'s phase on four of the host devices: the
+    2x2 (data, model) train step against the one-device step."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    import chip_smoke
+    from repro.configs import get_config
+    chip_smoke.phase_sharded(get_config("tacc-100m", smoke=True), chips=4,
+                             steps=3, batch=8, seq=32, seed=0)
+
+
 CHECKS = {k[len("check_"):]: v for k, v in list(globals().items())
           if k.startswith("check_")}
 
