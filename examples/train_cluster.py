@@ -20,7 +20,7 @@ from repro.core import (JobState, ResourceSpec, RuntimeEnv, TACC, TaskSpec)
 
 def run_cluster(root: str, *, train_entry: Dict[str, Any], steps: int,
                 ckpt_every: int, fail_at: int, urgent_at: int,
-                urgent_entry: Dict[str, Any], quantum: int = 10,
+                urgent_entry: Dict[str, Any],
                 log: Callable[[str], None] = print
                 ) -> Tuple[TACC, str, str, int]:
     """Submit a training job to a TACC service in ``root``, fail its node
@@ -38,7 +38,7 @@ def run_cluster(root: str, *, train_entry: Dict[str, Any], steps: int,
             return True
         return False
 
-    svc = TACC(root, policy="priority", quantum_steps=quantum,
+    svc = TACC(root, policy="priority", quantum_steps=10,
                fail_injector=fail_injector)
     train = TaskSpec(
         name="train-main", tenant="lab-a",

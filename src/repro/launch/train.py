@@ -30,20 +30,6 @@ from repro.train import (OptConfig, TrainConfig, build_train_step,
 from repro.train.step import state_shardings
 
 
-def sharded_train_step(cfg: ModelConfig, ocfg: OptConfig, tcfg: TrainConfig,
-                       mesh):
-    """The jitted train step for ``mesh`` (state donated), with the state
-    and batch shardings it takes."""
-    b_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    flags = RunFlags(distributed=True, token_axes=b_axes)
-    st_sh = state_shardings(cfg, mesh)
-    b_sh = NamedSharding(mesh, P(b_axes))      # every batch leaf: rows split
-    step_fn = jax.jit(build_train_step(cfg, ocfg, tcfg, flags),
-                      in_shardings=(st_sh, b_sh), out_shardings=(st_sh, None),
-                      donate_argnums=0)
-    return step_fn, st_sh, b_sh
-
-
 @dataclass
 class TrainResult:
     """What one :func:`train` call ran, and the compiled step to run more."""
@@ -72,7 +58,13 @@ def train(cfg: ModelConfig, mesh, *, steps: int, global_batch: int,
     ocfg = OptConfig(lr=lr, warmup_steps=max(steps // 10, 1),
                      total_steps=steps)
     tcfg = TrainConfig(n_microbatches=microbatches)
-    step_fn, st_sh, b_sh = sharded_train_step(cfg, ocfg, tcfg, mesh)
+    b_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    flags = RunFlags(distributed=True, token_axes=b_axes)
+    st_sh = state_shardings(cfg, mesh)
+    b_sh = NamedSharding(mesh, P(b_axes))      # every batch leaf: rows split
+    step_fn = jax.jit(build_train_step(cfg, ocfg, tcfg, flags),
+                      in_shardings=(st_sh, b_sh), out_shardings=(st_sh, None),
+                      donate_argnums=0)
     data = SyntheticLM(cfg, global_batch, seq_len, seed=seed,
                        host_id=jax.process_index(),
                        n_hosts=jax.process_count())
