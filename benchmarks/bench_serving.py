@@ -45,7 +45,7 @@ def main(n_requests: int = 12, max_new: int = 8):
     out = run_bench(n_requests, max_new)
     eng, _res, t_cb = out["batched"]
     eng1, _res1, t_seq = out["sequential"]
-    steps_cb, steps_seq = eng._steps, eng1._steps
+    steps_cb, steps_seq = (e.counters["decode_steps"] for e in (eng, eng1))
 
     tok = n_requests * max_new
     print("name,us_per_call,derived")

@@ -58,7 +58,7 @@ def main():
               f"(expected {expect}, {hit}/6 match)")
     print(f"\npattern accuracy {correct/total:.0%}; "
           f"{len(results)} requests in {dt:.1f}s with continuous batching "
-          f"({engine._steps} decode steps)")
+          f"({engine.counters['decode_steps']} decode steps)")
 
 
 if __name__ == "__main__":

@@ -91,8 +91,10 @@ def main() -> None:
               max_new=args.max_new)
     for g in r.results:
         print(f"req {g.request_id}: {g.prompt} -> {g.tokens}")
+    counts = " ".join(f"{k}={v}" for k, v in r.engine.counters.items())
     print(f"{len(r.results)} requests, {r.tokens} tokens in {r.wall_s:.1f}s "
-          f"({r.engine._steps} decode steps; warm-up {r.warmup_s:.1f}s)")
+          f"(warm-up {r.warmup_s:.1f}s); engine, warm-up included: "
+          f"{counts}")
 
 
 if __name__ == "__main__":

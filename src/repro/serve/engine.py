@@ -6,6 +6,14 @@ cache rows are spliced into the live batch cache, so decoding never stalls
 the whole batch for one admission (continuous batching). Finished slots free
 immediately. Greedy or temperature sampling.
 
+The engine's work is named for a trace. Its two compiled programs are
+``jit_serve_prefill`` and ``jit_serve_decode``. With ``repro.obs`` on,
+each admission is a ``serve.admit`` span (request id) around
+``serve.prefill``, ``serve.splice`` and ``serve.first_token``, and each
+decode step a ``serve.step`` span (step number) around ``serve.decode``,
+``serve.pin``, ``serve.fetch``, ``serve.sample`` and ``serve.retire``.
+``counters`` counts the work as plain ints, whether tracing is on or not.
+
 This is the ``jax_serve`` runtime the TACC execution layer provisions for
 inference tasks.
 """
@@ -19,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.models.transformer import (RunFlags, decode_step, init_cache,
                                       prefill)
@@ -62,11 +71,22 @@ class ServeEngine:
         self._slots = [_Slot() for _ in range(max_batch)]
         self.cache = init_cache(cfg, max_batch, max_seq)
         self._rng = np.random.RandomState(seed)
-        self._prefill1 = jax.jit(
-            lambda p, b, n: prefill(cfg, p, b, n, flags=flags))
-        self._decode = jax.jit(
-            lambda p, c, t: decode_step(cfg, p, c, t, flags=flags))
-        self._steps = 0
+
+        def serve_prefill(p, b, n):
+            return prefill(cfg, p, b, n, flags=flags)
+
+        def serve_decode(p, c, t):
+            return decode_step(cfg, p, c, t, flags=flags)
+
+        self._prefill1 = jax.jit(serve_prefill)
+        self._decode = jax.jit(serve_decode)
+        # decode_rows: occupied rows summed over steps; decode_kv_tokens:
+        # the cache entries those rows' steps read (prompt and tokens so
+        # far, the one each step writes included); prefill_padded_tokens:
+        # max_seq per admission, the prefill's one shape
+        self.counters = dict.fromkeys(
+            ("decode_steps", "decode_rows", "decode_kv_tokens", "admitted",
+             "prefill_tokens", "prefill_padded_tokens"), 0)
 
     # -- admission ---------------------------------------------------------
 
@@ -83,20 +103,28 @@ class ServeEngine:
         slot = self._free_slot()
         if slot is None:
             return None
-        arrived_s = time.perf_counter()
-        prompt = list(prompt)[: self.max_seq - max_new - 1]
-        toks = np.zeros((1, self.max_seq), np.int32)
-        toks[0, :len(prompt)] = prompt
-        lengths = jnp.asarray([len(prompt)], jnp.int32)
-        logits, row_cache = self._prefill1(
-            self.params, {"tokens": jnp.asarray(toks)}, lengths)
-        self._splice(slot, row_cache)
-        req = GenerationResult(self._next_id, prompt, arrived_s=arrived_s)
-        self._next_id += 1
-        first = self._pick(np.asarray(logits)[0])
-        req.tokens.append(int(first))
-        req.first_token_s = time.perf_counter()
-        self._slots[slot] = _Slot(req, max_new - 1, int(first))
+        with obs.span("serve.admit", request=self._next_id):
+            arrived_s = time.perf_counter()
+            with obs.span("serve.prefill"):
+                prompt = list(prompt)[: self.max_seq - max_new - 1]
+                toks = np.zeros((1, self.max_seq), np.int32)
+                toks[0, :len(prompt)] = prompt
+                lengths = jnp.asarray([len(prompt)], jnp.int32)
+                logits, row_cache = self._prefill1(
+                    self.params, {"tokens": jnp.asarray(toks)}, lengths)
+            with obs.span("serve.splice"):
+                self._splice(slot, row_cache)
+            req = GenerationResult(self._next_id, prompt, arrived_s=arrived_s)
+            self._next_id += 1
+            with obs.span("serve.first_token"):
+                first = self._pick(np.asarray(logits)[0])
+            req.tokens.append(int(first))
+            req.first_token_s = time.perf_counter()
+            self._slots[slot] = _Slot(req, max_new - 1, int(first))
+            c = self.counters
+            c["admitted"] += 1
+            c["prefill_tokens"] += len(prompt)
+            c["prefill_padded_tokens"] += self.max_seq
         return req
 
     def _splice(self, slot: int, row_cache) -> None:
@@ -137,32 +165,48 @@ class ServeEngine:
         occupied = np.asarray([s.request is not None for s in self._slots])
         if not occupied.any():
             return []
-        tokens = jnp.asarray([s.last_token for s in self._slots], jnp.int32)
-        prev_lengths = self.cache["lengths"]
-        logits, self.cache = self._decode(self.params, self.cache, tokens)
-        # the dense decode advances every row's length; freed slots must not
-        # keep walking (they would eventually run past max_seq and corrupt
-        # the position a future splice resumes from), so pin them in place
-        self.cache["lengths"] = jnp.where(jnp.asarray(occupied),
-                                          self.cache["lengths"], prev_lengths)
-        logits = np.asarray(logits)
-        now = time.perf_counter()
-        finished = []
-        self._steps += 1
-        for i, s in enumerate(self._slots):
-            if s.request is None:
-                continue
-            nxt = self._pick(logits[i])
-            s.request.tokens.append(nxt)
-            s.last_token = nxt
-            s.remaining -= 1
-            hit_eos = self.eos_id is not None and nxt == self.eos_id
-            if s.remaining <= 0 or hit_eos:
-                s.request.done = True
-                s.request.finished_s = now
-                finished.append(s.request)
-                self._slots[i] = _Slot()
-                self.cache["lengths"] = self.cache["lengths"].at[i].set(0)
+        c = self.counters
+        with obs.span("serve.step", step=c["decode_steps"]):
+            with obs.span("serve.decode"):
+                tokens = jnp.asarray([s.last_token for s in self._slots],
+                                     jnp.int32)
+                prev_lengths = self.cache["lengths"]
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  tokens)
+            # the dense decode advances every row's length; freed slots
+            # must not keep walking (they would eventually run past max_seq
+            # and corrupt the position a future splice resumes from), so
+            # pin them in place
+            with obs.span("serve.pin"):
+                self.cache["lengths"] = jnp.where(
+                    jnp.asarray(occupied), self.cache["lengths"],
+                    prev_lengths)
+            with obs.span("serve.fetch"):
+                logits = np.asarray(logits)
+            now = time.perf_counter()
+            finished, freed = [], []
+            c["decode_steps"] += 1
+            with obs.span("serve.sample"):
+                for i, s in enumerate(self._slots):
+                    if s.request is None:
+                        continue
+                    c["decode_rows"] += 1
+                    c["decode_kv_tokens"] += len(s.request.prompt) + len(
+                        s.request.tokens)
+                    nxt = self._pick(logits[i])
+                    s.request.tokens.append(nxt)
+                    s.last_token = nxt
+                    s.remaining -= 1
+                    hit_eos = self.eos_id is not None and nxt == self.eos_id
+                    if s.remaining <= 0 or hit_eos:
+                        s.request.done = True
+                        s.request.finished_s = now
+                        finished.append(s.request)
+                        freed.append(i)
+                        self._slots[i] = _Slot()
+            with obs.span("serve.retire"):
+                for i in freed:
+                    self.cache["lengths"] = self.cache["lengths"].at[i].set(0)
         return finished
 
     def run(self, requests: List[List[int]], max_new: int = 16

@@ -39,9 +39,9 @@ def test_freed_slot_lengths_pinned(model):
 def test_idle_engine_step_is_a_noop(model):
     cfg, params = model
     eng = ServeEngine(cfg, params, max_batch=2, max_seq=16)
-    before = eng._steps
+    before = eng.counters["decode_steps"]
     assert eng.step() == []
-    assert eng._steps == before                       # no decode was paid
+    assert eng.counters["decode_steps"] == before     # no decode was paid
     assert int(np.max(np.asarray(eng.cache["lengths"]))) == 0
 
 
@@ -72,6 +72,7 @@ def test_bench_serving_smoke_keeps_slot_invariants(model):
         assert all(len(r.tokens) == 2 for r in res)
         assert all(s.request is None for s in eng._slots)
         assert int(np.max(np.asarray(eng.cache["lengths"]))) == 0
-        assert eng._steps > 0
+        assert eng.counters["decode_steps"] > 0
     # batching must not serve in more decode steps than sequential
-    assert out["batched"][0]._steps <= out["sequential"][0]._steps
+    assert out["batched"][0].counters["decode_steps"] <= \
+        out["sequential"][0].counters["decode_steps"]
