@@ -243,14 +243,35 @@ def write_kv_cache(k_cache: jax.Array, v_cache: jax.Array,
     return jax.vmap(one)(k_cache, v_cache, k_new, v_new, lengths)
 
 
+def write_kv_token(cache: jax.Array, new: jax.Array, lengths: jax.Array,
+                   layer) -> jax.Array:
+    """Insert one new entry per sequence at its current length into one
+    layer of a stacked cache: ``cache[layer, b, lengths[b]] = new[b]``.
+    cache: (L, B, S, ...); new: (B, ...). A length at or past S writes
+    position S-1, as ``write_kv_cache``'s dynamic_update_slice clamps.
+    On a buffer the program owns (carried through the layer loop, donated
+    by its caller) XLA scatters in place: B entries, not L layers."""
+    B, S = cache.shape[1:3]
+    pos = jnp.clip(lengths, 0, S - 1)
+    return cache.at[layer, jnp.arange(B), pos].set(
+        new.astype(cache.dtype), mode="promise_in_bounds")
+
+
 def decode_self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
                           cache: Dict, lengths: jax.Array, *,
+                          layer=None,
                           seq_axes: Optional[Tuple[str, ...]] = None,
                           batch_axes: Tuple[str, ...] = (),
                           ) -> Tuple[jax.Array, Dict]:
     """One decode step. x: (B, 1, D). cache: {"k": (B,S,KV,HD), "v": ...}.
     ``lengths`` counts tokens already in the cache (new token goes at index
-    lengths, and attends to itself)."""
+    lengths, and attends to itself).
+
+    With ``layer`` (single shard only), ``cache`` holds every layer's keys
+    and values stacked, (L,B,S,KV,HD): the token is written at ``[layer]``
+    with ``write_kv_token``, that layer attends, and the whole stack is
+    returned for the caller's layer loop to carry. The arithmetic is
+    ``write_kv_cache`` then ``decode_attention_ref``, bit for bit."""
     q, k, v = project_qkv(cfg, p, x, lengths[:, None])
     q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]
     if seq_axes:
@@ -258,6 +279,10 @@ def decode_self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
         o, kc, vc = sharded_decode_attention(
             q1, cache["k"], cache["v"], k1, v1, lengths, seq_axes=seq_axes,
             batch_axes=batch_axes)
+    elif layer is not None:
+        kc = write_kv_token(cache["k"], k1, lengths, layer)
+        vc = write_kv_token(cache["v"], v1, lengths, layer)
+        o = decode_attention_ref(q1, kc[layer], vc[layer], lengths + 1)
     else:
         kc, vc = write_kv_cache(cache["k"], cache["v"], k1, v1, lengths)
         o = decode_attention_ref(q1, kc, vc, lengths + 1)

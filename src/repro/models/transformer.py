@@ -207,12 +207,14 @@ def _apply_ffn(cfg, spec, p, h, flags):
 
 def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
                        x: jax.Array, cache: Dict, lengths: jax.Array,
-                       flags: RunFlags):
-    """One layer, one decode token. Returns (x, new_cache)."""
+                       flags: RunFlags, layer=None):
+    """One layer, one decode token. Returns (x, new_cache). With ``layer``,
+    ``cache`` is the stacked cache of every period layer (see
+    ``carried_layers``) and the whole stack comes back."""
     h = L.apply_norm(cfg, p["mixer_norm"], x)
     if spec.mixer == "attn":
         y_mix, new_cache = A.decode_self_attention(
-            cfg, p["mixer"], h, cache, lengths,
+            cfg, p["mixer"], h, cache, lengths, layer=layer,
             seq_axes=flags.decode_seq_axes or None,
             batch_axes=flags.token_axes)
     elif spec.mixer == "mla":
@@ -353,6 +355,18 @@ def prefill(cfg: ModelConfig, params, batch, lengths, *, flags=RunFlags()):
     return logits, caches
 
 
+def carried_layers(cfg: ModelConfig, flags: RunFlags) -> Tuple[bool, ...]:
+    """For each layer of the period, whether ``decode_step`` carries its
+    cache through the layer loop whole and writes it in place, one token
+    per row (``attn`` on one shard), rather than reading and replacing it
+    whole as the loop's per-layer input and output (recurrent state,
+    MLA latents, the sequence-sharded path). Only a carried cache gains
+    from being donated to the decode program: one the loop reads and
+    replaces per layer, XLA copies whole on every step if it is donated."""
+    return tuple(s.mixer == "attn" and not flags.decode_seq_axes
+                 for s in cfg.period)
+
+
 def decode_step(cfg: ModelConfig, params, cache, tokens, *,
                 flags: RunFlags = RunFlags()):
     """One token for every sequence. tokens: (B,) or (B,1) int32 (or
@@ -375,29 +389,40 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
         x, c2 = apply_layer_decode(cfg, spec, p, x, c, lengths, flags)
         new_pre.append(c2)
 
-    def body(x, pc):
-        pp, cc = pc
+    # append-only caches ride the layer loop's carry and take one token per
+    # row in place; the rest pass through as scanned inputs and outputs
+    carried = carried_layers(cfg, flags)
+    kv = tuple(c if k else None for c, k in zip(cache["period"], carried))
+    state = tuple(None if k else c for c, k in zip(cache["period"], carried))
+
+    def body(carry, xs):
+        x, kv = carry
+        pp, st, i = xs
         pp = _precast(pp, cfg, flags)
-        new_caches = []
-        for spec, p, c in zip(cfg.period, pp, cc):
-            x, c2 = apply_layer_decode(cfg, spec, p, x, c, lengths, flags)
-            new_caches.append(c2)
-        return x, tuple(new_caches)
+        kv, st = list(kv), list(st)
+        for j, (spec, p) in enumerate(zip(cfg.period, pp)):
+            if carried[j]:
+                x, kv[j] = apply_layer_decode(cfg, spec, p, x, kv[j],
+                                              lengths, flags, layer=i)
+            else:
+                x, st[j] = apply_layer_decode(cfg, spec, p, x, st[j],
+                                              lengths, flags)
+        return (x, tuple(kv)), tuple(st)
 
     if flags.unroll_layers:
         new_list = []
         for i in range(cfg.n_periods):
-            pc = jax.tree.map(lambda a: a[i],
-                              (params["period"], cache["period"]))
-            x, caches = body(x, pc)
-            new_list.append(caches)
-        if new_list:
-            new_period = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
-        else:                # zero-period variant lowers
-            new_period = cache["period"]
+            pp, st = jax.tree.map(lambda a: a[i], (params["period"], state))
+            (x, kv), st = body((x, kv), (pp, st, i))
+            new_list.append(st)
+        if new_list:         # else the zero-period variant keeps its state
+            state = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
     else:
-        x, new_period = jax.lax.scan(body, x,
-                                     (params["period"], cache["period"]))
+        (x, kv), state = jax.lax.scan(
+            body, (x, kv),
+            (params["period"], state, jnp.arange(cfg.n_periods)))
+    new_period = tuple(c if k else s
+                       for c, s, k in zip(kv, state, carried))
     x = L.apply_norm(cfg, params["out_norm"], x)
     logits = L.unembed(cfg, params["embed"], x[:, 0])
     return logits, {"prelayers": tuple(new_pre), "period": new_period,

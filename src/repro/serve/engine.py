@@ -29,8 +29,8 @@ import numpy as np
 
 from repro import obs
 from repro.configs.base import ModelConfig
-from repro.models.transformer import (RunFlags, decode_step, init_cache,
-                                      prefill)
+from repro.models.transformer import (RunFlags, carried_layers, decode_step,
+                                      init_cache, prefill)
 
 
 @dataclass
@@ -54,7 +54,47 @@ class _Slot:
     last_token: int = 0
 
 
+def split_cache(cfg: ModelConfig, cache, flags: RunFlags = RunFlags()):
+    """``(owned, kept)``: the period caches ``decode_step`` carries through
+    its layer loop and writes in place (``carried_layers``), and the rest
+    of ``cache`` with those entries None."""
+    carried = carried_layers(cfg, flags)
+    owned = tuple(c if k else None for c, k in zip(cache["period"], carried))
+    kept = dict(cache, period=tuple(None if k else c for c, k in
+                                    zip(cache["period"], carried)))
+    return owned, kept
+
+
+def decode_program(cfg: ModelConfig, flags: RunFlags = RunFlags()):
+    """The engine's decode step, jitted as ``jit_serve_decode``:
+    ``(params, owned, kept, tokens) -> (logits, cache)``, the cache split
+    by ``split_cache``. ``owned`` is donated: the program writes each row's
+    new token into those buffers in place and hands them back in
+    ``cache``, so the arrays passed are invalid after the call. ``kept`` is
+    not: the caller may keep reading its ``lengths``, and the caches the
+    layer loop reads and replaces whole per layer would only be copied
+    whole if donated."""
+    def serve_decode(p, owned, kept, t):
+        period = tuple(k if o is None else o
+                       for o, k in zip(owned, kept["period"]))
+        return decode_step(cfg, p, dict(kept, period=period), t, flags=flags)
+
+    return jax.jit(serve_decode, donate_argnums=1)
+
+
 class ServeEngine:
+    """Continuous batching over a fixed (max_batch, max_seq) cache.
+
+    The decode program owns the caches it writes in place
+    (``decode_program``): each ``step`` donates ``self.cache``'s attention
+    keys and values and replaces them with the program's outputs, so an
+    array taken from ``self.cache`` before a ``step`` is invalid after it.
+    Read the cache through ``self.cache`` only. ``lengths`` stays out of
+    the donation, since ``step`` reads the lengths from before the call to
+    pin freed slots; so do recurrent state and MLA latents, which the
+    program replaces whole.
+    """
+
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
                  max_seq: int = 256, flags: RunFlags = RunFlags(),
                  eos_id: Optional[int] = None, seed: int = 0):
@@ -75,11 +115,8 @@ class ServeEngine:
         def serve_prefill(p, b, n):
             return prefill(cfg, p, b, n, flags=flags)
 
-        def serve_decode(p, c, t):
-            return decode_step(cfg, p, c, t, flags=flags)
-
         self._prefill1 = jax.jit(serve_prefill)
-        self._decode = jax.jit(serve_decode)
+        self._decode = decode_program(cfg, flags)
         # decode_rows: occupied rows summed over steps; decode_kv_tokens:
         # the cache entries those rows' steps read (prompt and tokens so
         # far, the one each step writes included); prefill_padded_tokens:
@@ -171,7 +208,8 @@ class ServeEngine:
                 tokens = jnp.asarray([s.last_token for s in self._slots],
                                      jnp.int32)
                 prev_lengths = self.cache["lengths"]
-                logits, self.cache = self._decode(self.params, self.cache,
+                owned, kept = split_cache(self.cfg, self.cache, self.flags)
+                logits, self.cache = self._decode(self.params, owned, kept,
                                                   tokens)
             # the dense decode advances every row's length; freed slots
             # must not keep walking (they would eventually run past max_seq
