@@ -9,11 +9,12 @@ from typing import Dict, List
 
 from repro.configs.base import (LayerSpec, MLAConfig, MambaConfig, ModelConfig,
                                 MoEConfig, ShapeConfig, SHAPES, XLSTMConfig,
-                                shape_applicable)
+                                YarnConfig, shape_applicable)
 
 from repro.configs import (starcoder2_15b, internlm2_1_8b, llama3_405b,
                            command_r_plus_104b, internvl2_2b, xlstm_125m,
                            qwen2_moe_a2_7b, deepseek_v2_236b,
+                           deepseek_v2_lite,
                            jamba_1_5_large_398b, musicgen_medium, tacc_100m)
 
 _MODULES = {
@@ -25,6 +26,8 @@ _MODULES = {
     "xlstm-125m": xlstm_125m,
     "qwen2-moe-a2.7b": qwen2_moe_a2_7b,
     "deepseek-v2-236b": deepseek_v2_236b,
+    "deepseek-v2-lite": deepseek_v2_lite,
+    "deepseek-v2-lite-ep8": deepseek_v2_lite.EP8,
     "jamba-1.5-large-398b": jamba_1_5_large_398b,
     "musicgen-medium": musicgen_medium,
     "tacc-100m": tacc_100m,

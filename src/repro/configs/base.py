@@ -26,6 +26,22 @@ class MoEConfig:
     # EP pads routed experts up to a multiple of the model-axis size; padded
     # experts get -inf router logits and zero parameters.
     pad_to: int = 0                # 0 = no padding requested
+    # The routed experts this device holds: ``n_held`` of them, from
+    # ``first_held`` on (0 = all). The router keeps every expert's output.
+    n_held: int = 0
+    first_held: int = 0
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN scaling of the rotary frequencies (arXiv:2309.00071), as
+    DeepSeek-V2 defines it."""
+    factor: float = 40.0
+    original_max_len: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -79,6 +95,7 @@ class ModelConfig:
     prelayers: Tuple[LayerSpec, ...] = ()
     # attention
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     pos_emb: str = "rope"          # rope | sincos | none
     use_bias: bool = False
     qkv_bias: bool = False         # bias on qkv only (Qwen-style)

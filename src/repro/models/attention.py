@@ -95,7 +95,8 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         chunk: int = 1024,
                         max_chunks: int = 16,
                         q_chunks: int = 4,
-                        unroll: bool = False) -> jax.Array:
+                        unroll: bool = False,
+                        scale: Optional[float] = None) -> jax.Array:
     """q: (B,Sq,H,HD); k,v: (B,Sk,H,HD) (kv already repeated to H heads).
 
     Online-softmax over a static (q-tile, kv-tile) grid; above-diagonal tiles
@@ -105,6 +106,7 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
     The kv-tile loop is a ``lax.scan`` by default (one tile of temp memory);
     ``unroll=True`` emits the tiles as straight-line ops so the dry-run's
     roofline variants get true FLOP counts (scan bodies are counted once).
+    ``scale`` multiplies the scores (default ``HD ** -0.5``).
     """
     B, Sq, H, HD = q.shape
     Sk = k.shape[1]
@@ -120,7 +122,8 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
     while Sq % nq:
         nq -= 1
     cq = Sq // nq
-    scale = 1.0 / math.sqrt(HD)
+    if scale is None:
+        scale = 1.0 / math.sqrt(HD)
 
     def tile(q_blk, q_lo, carry, k_lo, k_blk, v_blk):
         m, l, acc = carry

@@ -5,13 +5,14 @@ live beside the apply functions so a module is a (defs, apply) pair.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnConfig
 from repro.models.params import ParamDef
 
 
@@ -50,17 +51,51 @@ def rms_head_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
 # Rotary / sinusoidal position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float,
+               scaling: Optional[YarnConfig] = None) -> jax.Array:
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+    # YaRN as DeepSeek-V2 defines it: pairs below ``low`` keep their
+    # frequency, pairs above ``high`` are divided by the factor, and a
+    # linear ramp joins the two
+    low, high = yarn_range(head_dim, theta, scaling)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return freqs * keep + freqs / scaling.factor * (1.0 - keep)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
+def yarn_range(head_dim: int, theta: float, s: YarnConfig):
+    """The first and last rotary pair of YaRN's ramp."""
+    def dim(rotations):
+        return (head_dim * math.log(s.original_max_len
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim(s.beta_fast)), 0)
+    high = min(math.ceil(dim(s.beta_slow)), head_dim - 1)
+    return low, (high + 0.001 if high == low else high)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling: Optional[YarnConfig] = None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+    With YaRN ``scaling``, its frequencies, and cos and sin scaled by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    freqs = rope_freqs(hd, theta, scaling)              # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs    # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]                 # (..., seq, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDef
-from repro.models.layers import apply_rope
+from repro.models.layers import apply_rope, yarn_mscale
 from repro.models.attention import flash_attention_xla
 
 
@@ -57,7 +57,7 @@ def _project_q(cfg: ModelConfig, p: Dict, x: jax.Array, positions: jax.Array):
     else:
         q = jnp.einsum("bsD,Dhd->bshd", x, p["w_q"].astype(dt))
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -66,8 +66,19 @@ def _project_kv_latent(cfg: ModelConfig, p: Dict, x: jax.Array,
     dt = x.dtype
     ckv = _rms(x @ p["w_dkv"].astype(dt), p["kv_norm"], cfg.norm_eps)
     kr = x @ p["w_kr"].astype(dt)                       # (B,S,dr) shared head
-    kr = apply_rope(kr[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    kr = apply_rope(kr[:, :, None, :], positions, cfg.rope_theta,
+                    cfg.rope_scaling)[:, :, 0]
     return ckv, kr
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """(dn + dr) ** -0.5, times mscale(factor, mscale_all_dim) ** 2 under
+    YaRN."""
+    m, s = cfg.mla, cfg.rope_scaling
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    if s is not None:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
 
 
 def mla_self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
@@ -94,7 +105,7 @@ def mla_self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
     o = flash_attention_xla(q, k, v_pad(v, q.shape[-1]), causal=True,
                             lengths=lengths, chunk=cfg.attn_chunk,
                             max_chunks=cfg.max_attn_chunks,
-                            unroll=unroll)[..., :dv]
+                            unroll=unroll, scale=softmax_scale(cfg))[..., :dv]
     y = jnp.einsum("bshd,hdD->bsD", o, p["w_o"].astype(dt))
     return y, (ckv, kr)
 
@@ -118,7 +129,7 @@ def mla_decode_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
     H = cfg.n_heads
     dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
     dt = x.dtype
-    sm_scale = 1.0 / math.sqrt(dn + dr)
+    sm_scale = softmax_scale(cfg)
     q_nope, q_rope = _project_q(cfg, p, x, lengths[:, None])
     ckv_new, kr_new = _project_kv_latent(cfg, p, x, lengths[:, None])
     w_uk = p["w_ukv"].astype(dt)[..., :dn]              # (R, H, dn)

@@ -1,9 +1,15 @@
 """Mixture-of-Experts: token-choice top-k routing with expert parallelism.
 
-Two execution paths, validated against each other in tests:
+Three execution paths, validated against each other in tests:
 
+- ``held`` (one device, no mesh): the device holds a share of the routed
+  experts (``MoEConfig.n_held`` from ``first_held``; all by default). Every
+  token is routed over all experts; each held expert then runs on exactly
+  the tokens routed to it, grouped by a sort (``lax.ragged_dot``), with no
+  capacity and no dropped token. Assignments to experts held elsewhere add
+  nothing here: their part lies on other devices.
 - ``dense oracle``: every expert applied to every token, combined with the
-  sparse top-k weights. O(E) compute — only for tests/smoke configs.
+  sparse top-k weights. O(E) compute — the tests' oracle.
 - ``EP path``: experts sharded over the ``model`` mesh axis (``shard_map``).
   Each rank owns a strided subset of its data-shard's tokens, packs
   fixed-capacity per-destination buffers, exchanges them with
@@ -17,7 +23,7 @@ Aux outputs: switch-style load-balance loss and router z-loss.
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,12 +39,18 @@ def padded_experts(moe: MoEConfig) -> int:
     return max(moe.pad_to, moe.n_experts)
 
 
+def held_experts(moe: MoEConfig) -> int:
+    """Routed experts whose weights this device holds."""
+    return moe.n_held or padded_experts(moe)
+
+
 def moe_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
     moe = cfg.moe
-    E = padded_experts(moe)
+    E = held_experts(moe)
     D, F = cfg.d_model, moe.d_ff_expert
     out = {
-        "router": ParamDef((D, E), (None, "experts"), scale=1.0),
+        "router": ParamDef((D, padded_experts(moe)), (None, "experts"),
+                           scale=1.0),
         "w_in": ParamDef((E, D, 2 * F), ("experts", "embed", "mlp")),
         "w_out": ParamDef((E, F, D), ("experts", "mlp", "embed")),
     }
@@ -96,6 +108,50 @@ def _expert_ffn(cfg: ModelConfig, w_in: jax.Array, w_out: jax.Array,
     g, u = jnp.split(gu, 2, axis=-1)
     h = jax.nn.silu(g) * u
     return jnp.einsum("ecf,efd->ecd", h, w_out.astype(dt))
+
+
+# ---------------------------------------------------------------------------
+# Held experts, dropless (one device)
+# ---------------------------------------------------------------------------
+
+def moe_held(cfg: ModelConfig, p: Dict, x: jax.Array,
+             valid: Optional[jax.Array] = None
+             ) -> Tuple[jax.Array, Dict[str, jax.Array], Dict[str, jax.Array]]:
+    """x: (B, S, D); ``valid`` (broadcastable to (B, S)) marks the tokens
+    that route (padding and empty rows route to no expert). Returns (this
+    device's part of the layer, aux losses, counts): the held experts'
+    part of each token's routed sum plus the shared experts, and the
+    assignments that landed on held experts and the held experts that got
+    at least one token."""
+    moe = cfg.moe
+    B, S, D = x.shape
+    n, k, E = B * S, moe.top_k, held_experts(moe)
+    dt = x.dtype
+    flat = x.reshape(n, D)
+    with jax.named_scope("moe.route"):
+        idx, w, aux = _route(cfg, flat, p["router"])
+        local = idx - moe.first_held
+        here = (local >= 0) & (local < E)
+        if valid is not None:
+            here = here & jnp.broadcast_to(valid, (B, S)).reshape(n, 1)
+        group = jnp.where(here, local, E).reshape(-1)          # (n*k,)
+        order = jnp.argsort(group, stable=True)                # held first
+        sizes = jnp.sum(jax.nn.one_hot(group, E, dtype=jnp.int32), 0)
+        rows = flat[order // k]
+    with jax.named_scope("moe.experts"):
+        gu = jax.lax.ragged_dot(rows, p["w_in"].astype(dt), sizes)
+        g, u = jnp.split(gu, 2, axis=-1)
+        out = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["w_out"].astype(dt),
+                                 sizes)
+        # back to (token, choice) order; rows past the groups are not here
+        out = out[jnp.argsort(order)].reshape(n, k, D)
+        gate = jnp.where(here, w.astype(jnp.float32), 0.0)
+        y = jnp.einsum("nk,nkd->nd", gate,
+                       jnp.where(here[..., None], out, 0).astype(jnp.float32))
+    y = y.astype(dt).reshape(B, S, D) + _shared(cfg, p, x)
+    counts = {"assignments_here": jnp.sum(sizes),
+              "experts_touched": jnp.sum(sizes > 0).astype(jnp.int32)}
+    return y, _aux_loss(cfg, aux), counts
 
 
 # ---------------------------------------------------------------------------
@@ -259,12 +315,21 @@ def moe_ep(cfg: ModelConfig, p: Dict, x: jax.Array, *,
 
 
 def moe_apply(cfg: ModelConfig, p: Dict, x: jax.Array, *,
+              valid: Optional[jax.Array] = None,
               distributed: bool = False,
               ep_axis: str = "model",
               token_axes: Tuple[str, ...] = ("data",),
               combine: str = "psum",
-              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+              ) -> Tuple[jax.Array, Dict[str, jax.Array],
+                         Optional[Dict[str, jax.Array]]]:
+    """(y, aux losses, counts); counts (``moe_held``'s) are None on the
+    expert-parallel path, which holds every expert over the mesh and routes
+    padding too."""
     if distributed:
-        return moe_ep(cfg, p, x, ep_axis=ep_axis, token_axes=token_axes,
-                      combine=combine)
-    return moe_dense_oracle(cfg, p, x)
+        if held_experts(cfg.moe) != padded_experts(cfg.moe):
+            raise ValueError(f"{cfg.name}: a device's share of the experts "
+                             "runs on one device, not over a mesh")
+        y, aux = moe_ep(cfg, p, x, ep_axis=ep_axis, token_axes=token_axes,
+                        combine=combine)
+        return y, aux, None
+    return moe_held(cfg, p, x, valid)

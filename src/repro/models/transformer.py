@@ -175,42 +175,49 @@ def _apply_mixer_seq(cfg, spec, p, x, positions, lengths, flags, want_cache):
 
 def apply_layer_seq(cfg: ModelConfig, spec: LayerSpec, p: Dict, x: jax.Array,
                     positions, lengths, flags: RunFlags, want_cache: bool):
-    """One full layer over a whole sequence. Returns (x, cache, aux)."""
-    aux = zero_aux()
+    """One full layer over a whole sequence. Returns (x, cache, aux,
+    counts); counts (an MoE layer's, else None) leave padding out."""
+    aux, counts = zero_aux(), None
     h = L.apply_norm(cfg, p["mixer_norm"], x)
     y_mix, cache = _apply_mixer_seq(cfg, spec, p["mixer"], h, positions,
                                     lengths, flags, want_cache)
+    valid = None
+    if spec.ffn == "moe" and lengths is not None:
+        valid = positions < lengths[:, None]
     if spec.parallel and spec.ffn != "none":
-        y_ffn, aux = _apply_ffn(cfg, spec, p["ffn"], h, flags)
+        y_ffn, aux, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid)
         x = x + y_mix + y_ffn
-        return x, cache, aux
+        return x, cache, aux, counts
     x = x + y_mix
     if spec.ffn != "none":
         h = L.apply_norm(cfg, p["ffn_norm"], x)
-        y_ffn, aux = _apply_ffn(cfg, spec, p["ffn"], h, flags)
+        y_ffn, aux, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid)
         x = x + y_ffn
-    return x, cache, aux
+    return x, cache, aux, counts
 
 
-def _apply_ffn(cfg, spec, p, h, flags):
+def _apply_ffn(cfg, spec, p, h, flags, valid=None):
+    """(y, aux, counts); counts only from an MoE layer that reports them."""
     if spec.ffn == "moe":
-        y, aux_losses = MOE.moe_apply(cfg, p, h, distributed=flags.distributed,
-                                      ep_axis=flags.ep_axis,
-                                      token_axes=flags.token_axes,
-                                      combine=flags.moe_combine)
+        y, aux_losses, counts = MOE.moe_apply(
+            cfg, p, h, valid=valid, distributed=flags.distributed,
+            ep_axis=flags.ep_axis, token_axes=flags.token_axes,
+            combine=flags.moe_combine)
         aux = zero_aux()
         aux.update({k: jnp.asarray(v, jnp.float32)
                     for k, v in aux_losses.items()})
-        return y, aux
-    return L.apply_ffn(cfg, p, h), zero_aux()
+        return y, aux, counts
+    return L.apply_ffn(cfg, p, h), zero_aux(), None
 
 
 def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
                        x: jax.Array, cache: Dict, lengths: jax.Array,
                        flags: RunFlags, layer=None):
-    """One layer, one decode token. Returns (x, new_cache). With ``layer``,
-    ``cache`` is the stacked cache of every period layer (see
-    ``carried_layers``) and the whole stack comes back."""
+    """One layer, one decode token. Returns (x, new_cache, counts): an MoE
+    layer's counts, else None. With ``layer``, ``cache`` is the stacked
+    cache of every period layer (see ``carried_layers``) and the whole
+    stack comes back. An MoE layer routes no token of a row whose length
+    is 0: the engine keeps its free slots there."""
     h = L.apply_norm(cfg, p["mixer_norm"], x)
     if spec.mixer == "attn":
         y_mix, new_cache = A.decode_self_attention(
@@ -230,15 +237,32 @@ def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
         y_mix, new_cache = XL.slstm_decode(cfg, p["mixer"], h, cache)
     else:
         raise ValueError(spec.mixer)
+    valid = (lengths > 0)[:, None] if spec.ffn == "moe" else None
+    counts = None
     if spec.parallel and spec.ffn != "none":
-        y_ffn, _ = _apply_ffn(cfg, spec, p["ffn"], h, flags)
-        return x + y_mix + y_ffn, new_cache
-    x = x + y_mix
-    if spec.ffn != "none":
-        h = L.apply_norm(cfg, p["ffn_norm"], x)
-        y_ffn, _ = _apply_ffn(cfg, spec, p["ffn"], h, flags)
-        x = x + y_ffn
-    return x, new_cache
+        y_ffn, _, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid)
+        x = x + y_mix + y_ffn
+    else:
+        x = x + y_mix
+        if spec.ffn != "none":
+            h = L.apply_norm(cfg, p["ffn_norm"], x)
+            y_ffn, _, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags,
+                                          valid)
+            x = x + y_ffn
+    return x, new_cache, counts
+
+
+def moe_counts(per_layer) -> Dict[str, jax.Array]:
+    """Per-layer counts of the MoE layers as one int32 vector per count
+    (``assignments_here``, ``experts_touched``): prelayers first, then each
+    position of the period over the periods. ``per_layer`` holds scalars
+    (prelayers) and (n_periods,) vectors (period layers), or None for a
+    layer without counts."""
+    per_layer = [c for c in per_layer if c is not None]
+    if not per_layer:
+        return {}
+    return {k: jnp.concatenate([jnp.reshape(c[k], (-1,)) for c in per_layer])
+            for k in per_layer[0]}
 
 
 # ---------------------------------------------------------------------------
@@ -280,44 +304,54 @@ def _precast(pp, cfg: ModelConfig, flags: RunFlags):
 
 def forward(cfg: ModelConfig, params: Dict, batch: Dict[str, jax.Array], *,
             flags: RunFlags = RunFlags(), want_cache: bool = False,
-            lengths: Optional[jax.Array] = None):
-    """Full-sequence forward. Returns (hidden (B,S,D), caches, aux)."""
+            lengths: Optional[jax.Array] = None, with_counts: bool = False):
+    """Full-sequence forward. Returns (hidden (B,S,D), caches, aux), with
+    ``with_counts`` also the MoE layers' counts (``moe_counts``)."""
     x = _embed_input(cfg, params, batch)
     B, S, _ = x.shape
     positions = jnp.arange(S)[None, :]
     aux = zero_aux()
     x = _constrain(x, flags)
 
-    pre_caches = []
+    pre_caches, pre_counts = [], []
     for spec, p in zip(cfg.prelayers, params["prelayers"]):
-        x, c, a = apply_layer_seq(cfg, spec, _precast(p, cfg, flags), x,
-                                  positions, lengths, flags, want_cache)
+        x, c, a, n = apply_layer_seq(cfg, spec, _precast(p, cfg, flags), x,
+                                     positions, lengths, flags, want_cache)
         pre_caches.append(c)
+        pre_counts.append(n)
         aux = _add_aux(aux, a)
 
     def period_body(carry, pp):
         x, aux = carry
         x = _constrain(x, flags)
         pp = _precast(pp, cfg, flags)
-        caches = []
+        caches, counts = [], []
         for spec, p in zip(cfg.period, pp):
-            x, c, a = apply_layer_seq(cfg, spec, p, x, positions, lengths,
-                                      flags, want_cache)
+            x, c, a, n = apply_layer_seq(cfg, spec, p, x, positions, lengths,
+                                         flags, want_cache)
             caches.append(c)
+            counts.append(n)
             aux = _add_aux(aux, a)
+        if with_counts:
+            return (x, aux), (tuple(caches), tuple(counts))
         return (x, aux), tuple(caches)
 
     body = period_body
     if flags.remat == "full":
         body = jax.remat(period_body)
     if flags.unroll_layers:
-        cache_list = []
+        cache_list, count_list = [], []
         carry = (x, aux)
         for i in range(cfg.n_periods):
             pp = jax.tree.map(lambda a: a[i], params["period"])
             carry, caches = body(carry, pp)
+            if with_counts:
+                caches, counts = caches
+                count_list.append(counts)
             cache_list.append(caches)
         (x, aux) = carry
+        period_counts = (jax.tree.map(lambda *xs: jnp.stack(xs), *count_list)
+                         if count_list else ())
         period_caches = None
         if want_cache:
             if cache_list:
@@ -331,10 +365,14 @@ def forward(cfg: ModelConfig, params: Dict, batch: Dict[str, jax.Array], *,
     else:
         (x, aux), period_caches = jax.lax.scan(body, (x, aux),
                                                params["period"])
+        if with_counts:
+            period_caches, period_counts = period_caches
     x = L.apply_norm(cfg, params["out_norm"], x)
     caches = None
     if want_cache:
         caches = {"prelayers": tuple(pre_caches), "period": period_caches}
+    if with_counts:
+        return x, caches, aux, moe_counts(pre_counts + list(period_counts))
     return x, caches, aux
 
 
@@ -343,15 +381,21 @@ def train_logits(cfg: ModelConfig, params, batch, *, flags=RunFlags()):
     return L.unembed(cfg, params["embed"], x), aux
 
 
-def prefill(cfg: ModelConfig, params, batch, lengths, *, flags=RunFlags()):
-    """Prompt ingestion. Returns (last-position logits (B,V), cache)."""
-    x, caches, _ = forward(cfg, params, batch, flags=flags, want_cache=True,
-                           lengths=lengths)
+def prefill(cfg: ModelConfig, params, batch, lengths, *, flags=RunFlags(),
+            with_counts: bool = False):
+    """Prompt ingestion. Returns (last-position logits (B,V), cache), with
+    ``with_counts`` also the MoE layers' counts over the prompt tokens
+    (``moe_counts``; positions from ``lengths`` on route nowhere)."""
+    out = forward(cfg, params, batch, flags=flags, want_cache=True,
+                  lengths=lengths, with_counts=with_counts)
+    x, caches = out[0], out[1]
     B = x.shape[0]
     idx = jnp.clip(lengths - 1, 0, x.shape[1] - 1)
     last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
     logits = L.unembed(cfg, params["embed"], last)
     caches["lengths"] = lengths
+    if with_counts:
+        return logits, caches, out[3]
     return logits, caches
 
 
@@ -368,9 +412,11 @@ def carried_layers(cfg: ModelConfig, flags: RunFlags) -> Tuple[bool, ...]:
 
 
 def decode_step(cfg: ModelConfig, params, cache, tokens, *,
-                flags: RunFlags = RunFlags()):
+                flags: RunFlags = RunFlags(), with_counts: bool = False):
     """One token for every sequence. tokens: (B,) or (B,1) int32 (or
-    (B,1,D) frame embeds for input_mode=embeds). Returns (logits, cache)."""
+    (B,1,D) frame embeds for input_mode=embeds). Returns (logits, cache),
+    with ``with_counts`` also the MoE layers' counts (``moe_counts``; rows
+    of length 0 route nowhere)."""
     lengths = cache["lengths"]
     if cfg.input_mode == "embeds":
         x = tokens.astype(jnp.dtype(cfg.dtype)) @ \
@@ -383,11 +429,12 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
         x = x + L.sincos_pos_emb(lengths[:, None], cfg.d_model
                                  ).astype(x.dtype)
 
-    new_pre = []
+    new_pre, pre_counts = [], []
     for spec, p, c in zip(cfg.prelayers, params["prelayers"],
                           cache["prelayers"]):
-        x, c2 = apply_layer_decode(cfg, spec, p, x, c, lengths, flags)
+        x, c2, n = apply_layer_decode(cfg, spec, p, x, c, lengths, flags)
         new_pre.append(c2)
+        pre_counts.append(n)
 
     # append-only caches ride the layer loop's carry and take one token per
     # row in place; the rest pass through as scanned inputs and outputs
@@ -399,31 +446,46 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
         x, kv = carry
         pp, st, i = xs
         pp = _precast(pp, cfg, flags)
-        kv, st = list(kv), list(st)
+        kv, st, counts = list(kv), list(st), []
         for j, (spec, p) in enumerate(zip(cfg.period, pp)):
             if carried[j]:
-                x, kv[j] = apply_layer_decode(cfg, spec, p, x, kv[j],
-                                              lengths, flags, layer=i)
+                x, kv[j], n = apply_layer_decode(cfg, spec, p, x, kv[j],
+                                                 lengths, flags, layer=i)
             else:
-                x, st[j] = apply_layer_decode(cfg, spec, p, x, st[j],
-                                              lengths, flags)
+                x, st[j], n = apply_layer_decode(cfg, spec, p, x, st[j],
+                                                 lengths, flags)
+            counts.append(n)
+        if with_counts:
+            return (x, tuple(kv)), (tuple(st), tuple(counts))
         return (x, tuple(kv)), tuple(st)
 
+    period_counts = ()
     if flags.unroll_layers:
-        new_list = []
+        new_list, count_list = [], []
         for i in range(cfg.n_periods):
             pp, st = jax.tree.map(lambda a: a[i], (params["period"], state))
             (x, kv), st = body((x, kv), (pp, st, i))
+            if with_counts:
+                st, counts = st
+                count_list.append(counts)
             new_list.append(st)
         if new_list:         # else the zero-period variant keeps its state
             state = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
+        if count_list:
+            period_counts = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                         *count_list)
     else:
         (x, kv), state = jax.lax.scan(
             body, (x, kv),
             (params["period"], state, jnp.arange(cfg.n_periods)))
+        if with_counts:
+            state, period_counts = state
     new_period = tuple(c if k else s
                        for c, s, k in zip(kv, state, carried))
     x = L.apply_norm(cfg, params["out_norm"], x)
     logits = L.unembed(cfg, params["embed"], x[:, 0])
-    return logits, {"prelayers": tuple(new_pre), "period": new_period,
-                    "lengths": lengths + 1}
+    new_cache = {"prelayers": tuple(new_pre), "period": new_period,
+                 "lengths": lengths + 1}
+    if with_counts:
+        return logits, new_cache, moe_counts(pre_counts + list(period_counts))
+    return logits, new_cache

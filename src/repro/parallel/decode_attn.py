@@ -45,8 +45,8 @@ def _ambient_mesh(mesh):
 
 def _write_row(cache_row, new_row, idx, in_range):
     upd = jax.lax.dynamic_update_slice_in_dim(
-        cache_row, new_row[None], idx, axis=0)
-    return jnp.where(in_range, upd.astype(cache_row.dtype), cache_row)
+        cache_row, new_row[None].astype(cache_row.dtype), idx, axis=0)
+    return jnp.where(in_range, upd, cache_row)
 
 
 def _local_write(k_loc, v_loc, k_new, v_new, lengths, offset):

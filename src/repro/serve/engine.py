@@ -12,7 +12,9 @@ each admission is a ``serve.admit`` span (request id) around
 ``serve.prefill``, ``serve.splice`` and ``serve.first_token``, and each
 decode step a ``serve.step`` span (step number) around ``serve.decode``,
 ``serve.pin``, ``serve.fetch``, ``serve.sample`` and ``serve.retire``.
-``counters`` counts the work as plain ints, whether tracing is on or not.
+``counters`` counts the work as plain ints, whether tracing is on or not;
+for an MoE model both programs also return their expert layers' counts
+(``transformer.moe_counts``), which the counters add up.
 
 This is the ``jax_serve`` runtime the TACC execution layer provisions for
 inference tasks.
@@ -65,10 +67,12 @@ def split_cache(cfg: ModelConfig, cache, flags: RunFlags = RunFlags()):
     return owned, kept
 
 
-def decode_program(cfg: ModelConfig, flags: RunFlags = RunFlags()):
+def decode_program(cfg: ModelConfig, flags: RunFlags = RunFlags(),
+                   with_counts: bool = False):
     """The engine's decode step, jitted as ``jit_serve_decode``:
     ``(params, owned, kept, tokens) -> (logits, cache)``, the cache split
-    by ``split_cache``. ``owned`` is donated: the program writes each row's
+    by ``split_cache``; ``with_counts`` adds the MoE counts after the
+    cache. ``owned`` is donated: the program writes each row's
     new token into those buffers in place and hands them back in
     ``cache``, so the arrays passed are invalid after the call. ``kept`` is
     not: the caller may keep reading its ``lengths``, and the caches the
@@ -77,7 +81,8 @@ def decode_program(cfg: ModelConfig, flags: RunFlags = RunFlags()):
     def serve_decode(p, owned, kept, t):
         period = tuple(k if o is None else o
                        for o, k in zip(owned, kept["period"]))
-        return decode_step(cfg, p, dict(kept, period=period), t, flags=flags)
+        return decode_step(cfg, p, dict(kept, period=period), t, flags=flags,
+                           with_counts=with_counts)
 
     return jax.jit(serve_decode, donate_argnums=1)
 
@@ -111,19 +116,33 @@ class ServeEngine:
         self._slots = [_Slot() for _ in range(max_batch)]
         self.cache = init_cache(cfg, max_batch, max_seq)
         self._rng = np.random.RandomState(seed)
+        moe = cfg.moe is not None
 
         def serve_prefill(p, b, n):
-            return prefill(cfg, p, b, n, flags=flags)
+            return prefill(cfg, p, b, n, flags=flags, with_counts=moe)
 
         self._prefill1 = jax.jit(serve_prefill)
-        self._decode = decode_program(cfg, flags)
+        self._decode = decode_program(cfg, flags, with_counts=moe)
         # decode_rows: occupied rows summed over steps; decode_kv_tokens:
         # the cache entries those rows' steps read (prompt and tokens so
         # far, the one each step writes included); prefill_padded_tokens:
         # max_seq per admission, the prefill's one shape
-        self.counters = dict.fromkeys(
-            ("decode_steps", "decode_rows", "decode_kv_tokens", "admitted",
-             "prefill_tokens", "prefill_padded_tokens"), 0)
+        names = ["decode_steps", "decode_rows", "decode_kv_tokens",
+                 "admitted", "prefill_tokens", "prefill_padded_tokens"]
+        if moe:
+            # summed over MoE layers: decode assignments that landed on
+            # held experts and held experts that got a token (free slots
+            # route nowhere), and the prompt tokens' assignments here
+            names += ["moe_assignments_here", "moe_experts_touched",
+                      "moe_prefill_assignments_here"]
+        self.counters = dict.fromkeys(names, 0)
+
+    def _count_moe(self, counts, *names) -> None:
+        """Add the programs' per-layer MoE counts to ``counters``."""
+        got = jax.device_get(counts)
+        for name, key in zip(names, ("assignments_here", "experts_touched")):
+            if key in got:
+                self.counters[name] += int(np.sum(got[key]))
 
     # -- admission ---------------------------------------------------------
 
@@ -147,7 +166,7 @@ class ServeEngine:
                 toks = np.zeros((1, self.max_seq), np.int32)
                 toks[0, :len(prompt)] = prompt
                 lengths = jnp.asarray([len(prompt)], jnp.int32)
-                logits, row_cache = self._prefill1(
+                logits, row_cache, *counts = self._prefill1(
                     self.params, {"tokens": jnp.asarray(toks)}, lengths)
             with obs.span("serve.splice"):
                 self._splice(slot, row_cache)
@@ -162,6 +181,8 @@ class ServeEngine:
             c["admitted"] += 1
             c["prefill_tokens"] += len(prompt)
             c["prefill_padded_tokens"] += self.max_seq
+            if counts:
+                self._count_moe(counts[0], "moe_prefill_assignments_here")
         return req
 
     def _splice(self, slot: int, row_cache) -> None:
@@ -209,8 +230,8 @@ class ServeEngine:
                                      jnp.int32)
                 prev_lengths = self.cache["lengths"]
                 owned, kept = split_cache(self.cfg, self.cache, self.flags)
-                logits, self.cache = self._decode(self.params, owned, kept,
-                                                  tokens)
+                logits, self.cache, *counts = self._decode(
+                    self.params, owned, kept, tokens)
             # the dense decode advances every row's length; freed slots
             # must not keep walking (they would eventually run past max_seq
             # and corrupt the position a future splice resumes from), so
@@ -221,6 +242,9 @@ class ServeEngine:
                     prev_lengths)
             with obs.span("serve.fetch"):
                 logits = np.asarray(logits)
+                if counts:
+                    self._count_moe(counts[0], "moe_assignments_here",
+                                    "moe_experts_touched")
             now = time.perf_counter()
             finished, freed = [], []
             c["decode_steps"] += 1

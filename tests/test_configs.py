@@ -15,6 +15,9 @@ NOMINAL = {
     "xlstm-125m": 125e6,
     "qwen2-moe-a2.7b": 14.3e9,      # total (A2.7B is the *active* count)
     "deepseek-v2-236b": 236e9,
+    "deepseek-v2-lite": 15.7e9,
+    # one chip's share of 8-chip expert parallelism: 8 of 64 experts held
+    "deepseek-v2-lite-ep8": 3.1e9,
     "jamba-1.5-large-398b": 398e9,
     "musicgen-medium": 1.5e9,
 }

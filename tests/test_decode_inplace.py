@@ -24,8 +24,10 @@ B, S, STEPS = 4, 16, 8
 LENGTHS = (0, 5, 9, S - STEPS)
 
 
-def oracle_decode_step(cfg, params, cache, tokens, unroll=False):
-    """Write-then-attend decode, each layer on its own cache slice."""
+def oracle_decode_step(cfg, params, cache, tokens, unroll=False,
+                       with_counts=False):
+    """Write-then-attend decode, each layer on its own cache slice; with
+    ``with_counts``, also the MoE layers' counts, as ``decode_step``."""
     flags = T.RunFlags()
     lengths = cache["lengths"]
     dt = jnp.dtype(cfg.dtype)
@@ -33,18 +35,21 @@ def oracle_decode_step(cfg, params, cache, tokens, unroll=False):
     x = x * jnp.asarray(cfg.embedding_multiplier, dt)
     if cfg.pos_emb == "sincos":
         x = x + L.sincos_pos_emb(lengths[:, None], cfg.d_model).astype(dt)
-    pre = []
+    pre, pre_counts = [], []
     for spec, p, c in zip(cfg.prelayers, params["prelayers"],
                           cache["prelayers"]):
-        x, c = T.apply_layer_decode(cfg, spec, p, x, c, lengths, flags)
+        x, c, n = T.apply_layer_decode(cfg, spec, p, x, c, lengths, flags)
         pre.append(c)
+        pre_counts.append(n)
 
     def body(x, pc):
-        new = []
+        new, counts = [], []
         for spec, p, c in zip(cfg.period, *pc):
-            x, c = T.apply_layer_decode(cfg, spec, p, x, c, lengths, flags)
+            x, c, n = T.apply_layer_decode(cfg, spec, p, x, c, lengths,
+                                           flags)
             new.append(c)
-        return x, tuple(new)
+            counts.append(n)
+        return x, (tuple(new), tuple(counts))
 
     if unroll:
         outs = []
@@ -52,14 +57,17 @@ def oracle_decode_step(cfg, params, cache, tokens, unroll=False):
             x, c = body(x, jax.tree.map(
                 lambda a: a[i], (params["period"], cache["period"])))
             outs.append(c)
-        period = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+        period, counts = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
     else:
-        x, period = jax.lax.scan(body, x,
-                                 (params["period"], cache["period"]))
+        x, (period, counts) = jax.lax.scan(
+            body, x, (params["period"], cache["period"]))
     x = L.apply_norm(cfg, params["out_norm"], x)
     logits = L.unembed(cfg, params["embed"], x[:, 0])
-    return logits, {"prelayers": tuple(pre), "period": period,
-                    "lengths": lengths + 1}
+    new_cache = {"prelayers": tuple(pre), "period": period,
+                 "lengths": lengths + 1}
+    if with_counts:
+        return logits, new_cache, T.moe_counts(pre_counts + list(counts))
+    return logits, new_cache
 
 
 def _filled_cache(cfg):
@@ -106,13 +114,15 @@ def test_decode_matches_write_then_attend(arch, unroll):
 
 
 def _oracle_engine(cfg, params, **kw):
-    """An engine that drives the oracle step, donating nothing."""
+    """An engine that drives the oracle step, donating nothing (and
+    returning the MoE counts, as the engine's own step does)."""
     engine = ServeEngine(cfg, params, **kw)
 
     def step(p, owned, kept, t):
         period = tuple(k if o is None else o
                        for o, k in zip(owned, kept["period"]))
-        return oracle_decode_step(cfg, p, dict(kept, period=period), t)
+        return oracle_decode_step(cfg, p, dict(kept, period=period), t,
+                                  with_counts=cfg.moe is not None)
 
     engine._decode = jax.jit(step)
     return engine

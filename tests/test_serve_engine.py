@@ -76,3 +76,62 @@ def test_bench_serving_smoke_keeps_slot_invariants(model):
     # batching must not serve in more decode steps than sequential
     assert out["batched"][0].counters["decode_steps"] <= \
         out["sequential"][0].counters["decode_steps"]
+
+
+# sha256 of ``.lower(...).as_text()`` of InternLM2's two serving programs at
+# smoke size (max_batch 4, max_seq 64; jax 0.9.0), as they were before the
+# engine learned the MoE counters: a dense model's programs must not change
+# when an MoE model's do. A change that rightly alters them updates these.
+INTERNLM2_PROGRAMS = {
+    "jit_serve_prefill":
+        "6be2f4b1cf383d3f65a58c26e925d3816340865ac5e40646c2b73a15bbec9ca4",
+    "jit_serve_decode":
+        "a1b53a379198c852c4d51149980cfc6cefc28dd038d3818bd926c730933b2a2e",
+}
+
+
+def test_dense_serving_programs_lower_unchanged():
+    import hashlib
+
+    import jax.numpy as jnp
+
+    from repro.models import abstract_params
+    from repro.serve.engine import split_cache
+    cfg = get_config("internlm2-1.8b", smoke=True)
+    params = abstract_params(model_defs(cfg))
+    eng = ServeEngine(cfg, jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype), params), max_batch=4,
+        max_seq=64)
+    owned, kept = split_cache(cfg, eng.cache)
+    texts = {
+        "jit_serve_prefill": eng._prefill1.lower(
+            params, {"tokens": jax.ShapeDtypeStruct((1, 64), jnp.int32)},
+            jax.ShapeDtypeStruct((1,), jnp.int32)).as_text(),
+        "jit_serve_decode": eng._decode.lower(
+            params, owned, kept,
+            jax.ShapeDtypeStruct((4,), jnp.int32)).as_text()}
+    for name, text in texts.items():
+        assert name in text
+        assert hashlib.sha256(text.encode()).hexdigest() == \
+            INTERNLM2_PROGRAMS[name], name
+
+
+def test_moe_counters_add_up_the_programs_counts():
+    """For an MoE model the engine's counters add up what its programs
+    count: every real prompt token's top-k assignments when all experts are
+    held, and none for a free slot's row."""
+    cfg = get_config("deepseek-v2-lite", smoke=True)     # every expert held
+    params = init_params(model_defs(cfg), jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, params, max_batch=3, max_seq=32)
+    eng.run([[1, 2, 3, 4, 5], [6, 7]], max_new=4)
+    c = eng.counters
+    n_moe = cfg.n_layers - len(cfg.prelayers)
+    k = cfg.moe.top_k
+    assert c["moe_prefill_assignments_here"] == 7 * k * n_moe
+    assert c["moe_assignments_here"] == c["decode_rows"] * k * n_moe
+    assert 0 < c["moe_experts_touched"] <= c["decode_steps"] * n_moe * \
+        cfg.moe.n_experts
+    dense = ServeEngine(get_config("internlm2-1.8b", smoke=True), init_params(
+        model_defs(get_config("internlm2-1.8b", smoke=True)),
+        jax.random.PRNGKey(0)), max_batch=2, max_seq=16)
+    assert not any(name.startswith("moe_") for name in dense.counters)

@@ -151,3 +151,45 @@ def test_serve_decode_donates_only_what_it_writes_in_place_for_v5e(
                      if s.mixer == "attn")
     assert mem.alias_size_in_bytes == attn_bytes
     assert mem.temp_size_in_bytes <= base.temp_size_in_bytes
+
+
+def test_held_expert_layer_compiles_for_v5e(one_chip):
+    """The dropless held-expert layer at DeepSeek-V2-Lite's widths (d_model
+    2048, 8 of 64 experts of 1408 held, top-6, 2 shared), over a decode
+    step's 16 rows and a prefill's 4096: the grouped matmuls are the TPU's
+    ragged-dot kernel, and the temporaries stay under what computing all 8
+    held experts on every assignment would take."""
+    from repro.models.moe import moe_held
+    cfg = get_config("deepseek-v2-lite-ep8")
+    p = abstract_params(model_defs(cfg))["period"][0]["ffn"]
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape[1:], jnp.bfloat16, sharding=one_chip), p)
+    moe = cfg.moe
+    for rows in (16, 4096):
+        x = jax.ShapeDtypeStruct((1, rows, cfg.d_model), jnp.bfloat16,
+                                 sharding=one_chip)
+        valid = jax.ShapeDtypeStruct((1, rows), jnp.bool_, sharding=one_chip)
+        c = jax.jit(lambda p, x, v: moe_held(cfg, p, x, v)).lower(
+            p, x, valid).compile()
+        assert "ragged-dot" in c.as_text()
+        dense = moe.n_held * rows * moe.top_k * 2 * moe.d_ff_expert * 2
+        assert c.memory_analysis().temp_size_in_bytes < dense
+
+
+def test_yarn_mla_decode_compiles_for_v5e(one_chip):
+    """ServeEngine's decode program of the DeepSeek-V2-Lite share, YaRN on,
+    with its MoE counts, at the cell's widths and 16 rows of 4096 positions,
+    depth cut to the dense layer and one MoE layer: it compiles, donates
+    nothing (MLA latents are replaced whole), and its temporaries stay
+    under a tenth of the latent cache it rewrites."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-ep8"), n_layers=2)
+    params, cache, tokens = _decode_args(cfg, 16, 4096, one_chip)
+    owned, kept = split_cache(cfg, cache)
+    c = decode_program(cfg, with_counts=True).lower(
+        params, owned, kept, tokens).compile()
+    mem = c.memory_analysis()
+    latents = _nbytes(cache["period"]) + _nbytes(cache["prelayers"])
+    assert latents == 2 * 16 * 4096 * (512 + 64) * 2
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes < latents / 10
+    assert "ragged-dot" in c.as_text()
