@@ -115,14 +115,21 @@ def _expert_ffn(cfg: ModelConfig, w_in: jax.Array, w_out: jax.Array,
 # ---------------------------------------------------------------------------
 
 def moe_held(cfg: ModelConfig, p: Dict, x: jax.Array,
-             valid: Optional[jax.Array] = None
+             valid: Optional[jax.Array] = None, layer=None
              ) -> Tuple[jax.Array, Dict[str, jax.Array], Dict[str, jax.Array]]:
     """x: (B, S, D); ``valid`` (broadcastable to (B, S)) marks the tokens
     that route (padding and empty rows route to no expert). Returns (this
     device's part of the layer, aux losses, counts): the held experts'
     part of each token's routed sum plus the shared experts, and the
     assignments that landed on held experts and the held experts that got
-    at least one token."""
+    at least one token.
+
+    With ``layer``, ``w_in`` and ``w_out`` are the stacks of every period
+    layer's held experts, (L, E, D, 2F) and (L, E, F, D), and this is layer
+    ``layer`` of them. The grouped matmuls then read the stack in place as
+    L·E groups, all empty but this layer's E: a slice of the stack would be
+    copied whole before the TPU's ragged-dot kernel, which fuses no slice
+    into its operands."""
     moe = cfg.moe
     B, S, D = x.shape
     n, k, E = B * S, moe.top_k, held_experts(moe)
@@ -138,11 +145,17 @@ def moe_held(cfg: ModelConfig, p: Dict, x: jax.Array,
         order = jnp.argsort(group, stable=True)                # held first
         sizes = jnp.sum(jax.nn.one_hot(group, E, dtype=jnp.int32), 0)
         rows = flat[order // k]
+    w_in, w_out, groups = p["w_in"], p["w_out"], sizes
+    if layer is not None:
+        G = w_in.shape[0] * E
+        w_in = w_in.reshape((G,) + w_in.shape[2:])
+        w_out = w_out.reshape((G,) + w_out.shape[2:])
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((G,), sizes.dtype), sizes, (layer * E,))
     with jax.named_scope("moe.experts"):
-        gu = jax.lax.ragged_dot(rows, p["w_in"].astype(dt), sizes)
+        gu = jax.lax.ragged_dot(rows, w_in.astype(dt), groups)
         g, u = jnp.split(gu, 2, axis=-1)
-        out = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["w_out"].astype(dt),
-                                 sizes)
+        out = jax.lax.ragged_dot(jax.nn.silu(g) * u, w_out.astype(dt), groups)
         # back to (token, choice) order; rows past the groups are not here
         out = out[jnp.argsort(order)].reshape(n, k, D)
         gate = jnp.where(here, w.astype(jnp.float32), 0.0)
@@ -315,7 +328,7 @@ def moe_ep(cfg: ModelConfig, p: Dict, x: jax.Array, *,
 
 
 def moe_apply(cfg: ModelConfig, p: Dict, x: jax.Array, *,
-              valid: Optional[jax.Array] = None,
+              valid: Optional[jax.Array] = None, layer=None,
               distributed: bool = False,
               ep_axis: str = "model",
               token_axes: Tuple[str, ...] = ("data",),
@@ -324,7 +337,7 @@ def moe_apply(cfg: ModelConfig, p: Dict, x: jax.Array, *,
                          Optional[Dict[str, jax.Array]]]:
     """(y, aux losses, counts); counts (``moe_held``'s) are None on the
     expert-parallel path, which holds every expert over the mesh and routes
-    padding too."""
+    padding too. ``layer``: ``moe_held``'s, for stacked held experts."""
     if distributed:
         if held_experts(cfg.moe) != padded_experts(cfg.moe):
             raise ValueError(f"{cfg.name}: a device's share of the experts "
@@ -332,4 +345,4 @@ def moe_apply(cfg: ModelConfig, p: Dict, x: jax.Array, *,
         y, aux = moe_ep(cfg, p, x, ep_axis=ep_axis, token_axes=token_axes,
                         combine=combine)
         return y, aux, None
-    return moe_held(cfg, p, x, valid)
+    return moe_held(cfg, p, x, valid, layer)

@@ -174,9 +174,12 @@ def _apply_mixer_seq(cfg, spec, p, x, positions, lengths, flags, want_cache):
 
 
 def apply_layer_seq(cfg: ModelConfig, spec: LayerSpec, p: Dict, x: jax.Array,
-                    positions, lengths, flags: RunFlags, want_cache: bool):
+                    positions, lengths, flags: RunFlags, want_cache: bool,
+                    expert_layer=None):
     """One full layer over a whole sequence. Returns (x, cache, aux,
-    counts); counts (an MoE layer's, else None) leave padding out."""
+    counts); counts (an MoE layer's, else None) leave padding out. With
+    ``expert_layer``, the MoE weights ``w_in`` and ``w_out`` are the stacks
+    over the periods (``held_stacks``) and this layer is that period."""
     aux, counts = zero_aux(), None
     h = L.apply_norm(cfg, p["mixer_norm"], x)
     y_mix, cache = _apply_mixer_seq(cfg, spec, p["mixer"], h, positions,
@@ -185,22 +188,25 @@ def apply_layer_seq(cfg: ModelConfig, spec: LayerSpec, p: Dict, x: jax.Array,
     if spec.ffn == "moe" and lengths is not None:
         valid = positions < lengths[:, None]
     if spec.parallel and spec.ffn != "none":
-        y_ffn, aux, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid)
+        y_ffn, aux, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid,
+                                        expert_layer)
         x = x + y_mix + y_ffn
         return x, cache, aux, counts
     x = x + y_mix
     if spec.ffn != "none":
         h = L.apply_norm(cfg, p["ffn_norm"], x)
-        y_ffn, aux, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid)
+        y_ffn, aux, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid,
+                                        expert_layer)
         x = x + y_ffn
     return x, cache, aux, counts
 
 
-def _apply_ffn(cfg, spec, p, h, flags, valid=None):
+def _apply_ffn(cfg, spec, p, h, flags, valid=None, expert_layer=None):
     """(y, aux, counts); counts only from an MoE layer that reports them."""
     if spec.ffn == "moe":
         y, aux_losses, counts = MOE.moe_apply(
-            cfg, p, h, valid=valid, distributed=flags.distributed,
+            cfg, p, h, valid=valid, layer=expert_layer,
+            distributed=flags.distributed,
             ep_axis=flags.ep_axis, token_axes=flags.token_axes,
             combine=flags.moe_combine)
         aux = zero_aux()
@@ -212,12 +218,13 @@ def _apply_ffn(cfg, spec, p, h, flags, valid=None):
 
 def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
                        x: jax.Array, cache: Dict, lengths: jax.Array,
-                       flags: RunFlags, layer=None):
+                       flags: RunFlags, layer=None, expert_layer=None):
     """One layer, one decode token. Returns (x, new_cache, counts): an MoE
     layer's counts, else None. With ``layer``, ``cache`` is the stacked
     cache of every period layer (see ``carried_layers``) and the whole
-    stack comes back. An MoE layer routes no token of a row whose length
-    is 0: the engine keeps its free slots there."""
+    stack comes back; ``expert_layer`` is ``apply_layer_seq``'s. An MoE
+    layer routes no token of a row whose length is 0: the engine keeps its
+    free slots there."""
     h = L.apply_norm(cfg, p["mixer_norm"], x)
     if spec.mixer == "attn":
         y_mix, new_cache = A.decode_self_attention(
@@ -240,14 +247,15 @@ def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
     valid = (lengths > 0)[:, None] if spec.ffn == "moe" else None
     counts = None
     if spec.parallel and spec.ffn != "none":
-        y_ffn, _, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid)
+        y_ffn, _, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid,
+                                      expert_layer)
         x = x + y_mix + y_ffn
     else:
         x = x + y_mix
         if spec.ffn != "none":
             h = L.apply_norm(cfg, p["ffn_norm"], x)
             y_ffn, _, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags,
-                                          valid)
+                                          valid, expert_layer)
             x = x + y_ffn
     return x, new_cache, counts
 
@@ -302,6 +310,37 @@ def _precast(pp, cfg: ModelConfig, flags: RunFlags):
     return jax.tree_util.tree_map_with_path(f, pp)
 
 
+def held_stacks(cfg: ModelConfig, period, flags: RunFlags):
+    """Take each MoE position's held experts (``w_in`` and ``w_out``,
+    stacked over the periods) out of the period parameters the layer loop
+    slices. Returns (what the loop slices, per position of the period the
+    stacks, ``_precast`` once here, or None). Nothing is taken on the
+    expert-parallel path, which shards the experts itself.
+
+    The serving programs hand the stacks whole to every layer, so no layer's
+    experts are copied out of them (``moe_held``). Training keeps the
+    slice: the gradient of a stack the loop closes over is a whole stack
+    summed on every layer."""
+    if flags.distributed or not cfg.n_periods:
+        return period, (None,) * len(cfg.period)
+    sliced, stacks = [], []
+    for spec, p in zip(cfg.period, period):
+        if spec.ffn != "moe":
+            sliced.append(p)
+            stacks.append(None)
+            continue
+        ffn = dict(p["ffn"])
+        stacks.append(_precast({k: ffn.pop(k) for k in ("w_in", "w_out")},
+                               cfg, flags))
+        sliced.append(dict(p, ffn=ffn))
+    return tuple(sliced), tuple(stacks)
+
+
+def _with_stack(p, stack):
+    """A layer's parameters with its held-expert stacks put back."""
+    return p if stack is None else dict(p, ffn=dict(p["ffn"], **stack))
+
+
 def forward(cfg: ModelConfig, params: Dict, batch: Dict[str, jax.Array], *,
             flags: RunFlags = RunFlags(), want_cache: bool = False,
             lengths: Optional[jax.Array] = None, with_counts: bool = False):
@@ -321,14 +360,23 @@ def forward(cfg: ModelConfig, params: Dict, batch: Dict[str, jax.Array], *,
         pre_counts.append(n)
         aux = _add_aux(aux, a)
 
-    def period_body(carry, pp):
+    # a forward that builds a cache serves: its held experts are read in
+    # place in their stacks, with each period's index beside its slice
+    period, stacks = params["period"], (None,) * len(cfg.period)
+    if want_cache:
+        period, stacks = held_stacks(cfg, period, flags)
+    stacked = any(st is not None for st in stacks)
+
+    def period_body(carry, xs):
         x, aux = carry
+        pp, i = xs if stacked else (xs, None)
         x = _constrain(x, flags)
         pp = _precast(pp, cfg, flags)
         caches, counts = [], []
-        for spec, p in zip(cfg.period, pp):
-            x, c, a, n = apply_layer_seq(cfg, spec, p, x, positions, lengths,
-                                         flags, want_cache)
+        for spec, p, st in zip(cfg.period, pp, stacks):
+            x, c, a, n = apply_layer_seq(
+                cfg, spec, _with_stack(p, st), x, positions, lengths, flags,
+                want_cache, expert_layer=None if st is None else i)
             caches.append(c)
             counts.append(n)
             aux = _add_aux(aux, a)
@@ -343,8 +391,8 @@ def forward(cfg: ModelConfig, params: Dict, batch: Dict[str, jax.Array], *,
         cache_list, count_list = [], []
         carry = (x, aux)
         for i in range(cfg.n_periods):
-            pp = jax.tree.map(lambda a: a[i], params["period"])
-            carry, caches = body(carry, pp)
+            pp = jax.tree.map(lambda a: a[i], period)
+            carry, caches = body(carry, (pp, i) if stacked else pp)
             if with_counts:
                 caches, counts = caches
                 count_list.append(counts)
@@ -363,8 +411,8 @@ def forward(cfg: ModelConfig, params: Dict, batch: Dict[str, jax.Array], *,
                                  layer_cache(cfg, s, x.shape[0], x.shape[1]))
                     for s in cfg.period)
     else:
-        (x, aux), period_caches = jax.lax.scan(body, (x, aux),
-                                               params["period"])
+        xs = (period, jnp.arange(cfg.n_periods)) if stacked else period
+        (x, aux), period_caches = jax.lax.scan(body, (x, aux), xs)
         if with_counts:
             period_caches, period_counts = period_caches
     x = L.apply_norm(cfg, params["out_norm"], x)
@@ -441,6 +489,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
     carried = carried_layers(cfg, flags)
     kv = tuple(c if k else None for c, k in zip(cache["period"], carried))
     state = tuple(None if k else c for c, k in zip(cache["period"], carried))
+    period, stacks = held_stacks(cfg, params["period"], flags)
 
     def body(carry, xs):
         x, kv = carry
@@ -448,12 +497,16 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
         pp = _precast(pp, cfg, flags)
         kv, st, counts = list(kv), list(st), []
         for j, (spec, p) in enumerate(zip(cfg.period, pp)):
+            p = _with_stack(p, stacks[j])
+            at = None if stacks[j] is None else i
             if carried[j]:
                 x, kv[j], n = apply_layer_decode(cfg, spec, p, x, kv[j],
-                                                 lengths, flags, layer=i)
+                                                 lengths, flags, layer=i,
+                                                 expert_layer=at)
             else:
                 x, st[j], n = apply_layer_decode(cfg, spec, p, x, st[j],
-                                                 lengths, flags)
+                                                 lengths, flags,
+                                                 expert_layer=at)
             counts.append(n)
         if with_counts:
             return (x, tuple(kv)), (tuple(st), tuple(counts))
@@ -463,7 +516,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
     if flags.unroll_layers:
         new_list, count_list = [], []
         for i in range(cfg.n_periods):
-            pp, st = jax.tree.map(lambda a: a[i], (params["period"], state))
+            pp, st = jax.tree.map(lambda a: a[i], (period, state))
             (x, kv), st = body((x, kv), (pp, st, i))
             if with_counts:
                 st, counts = st
@@ -477,7 +530,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
     else:
         (x, kv), state = jax.lax.scan(
             body, (x, kv),
-            (params["period"], state, jnp.arange(cfg.n_periods)))
+            (period, state, jnp.arange(cfg.n_periods)))
         if with_counts:
             state, period_counts = state
     new_period = tuple(c if k else s

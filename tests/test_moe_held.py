@@ -6,6 +6,7 @@ experts held elsewhere zeroed, so the oracle computes exactly this device's
 part; four disjoint shares add up to the uncut layer; the counts it returns
 are checked against the routing the oracle's router makes.
 """
+import functools
 from dataclasses import replace
 
 import jax
@@ -135,6 +136,91 @@ def test_four_disjoint_shares_add_up_to_the_uncut_layer():
         n_tok * SMOKE.moe.top_k
 
 
+N_LAYERS = 5          # a stack of five period layers' held experts
+
+
+def _rows(kind, shift):
+    """A decode step's 16 rows (every third empty) or two prefill rows of
+    24 positions (17 real, then padding; the second row empty), with the
+    ``valid`` mask the serving programs pass; features ``shift`` + N(0, 1)."""
+    if kind == "decode":
+        x = shift + _x(seed=7, b=16, s=1)
+        return x, (jnp.arange(16) % 3 != 0)[:, None]
+    x = shift + _x(seed=8, b=2, s=24)
+    return x, jnp.arange(24)[None, :] < jnp.asarray([[17], [0]])
+
+
+def _whole(p, seed):
+    """Whole-number expert weights under which every sum the grouped
+    matmuls make is exact in float32, whatever its order: gates of 1-2 over
+    inputs of 1-2 sum to at least 64, where silu is the identity in
+    float32; up and down projections of -1, 0, 1."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    F = p["w_out"].shape[1]
+    ints = lambda k, shape, lo, hi: jax.random.randint(
+        k, shape, lo, hi + 1).astype(jnp.float32)
+    gate = ints(k1, p["w_in"].shape[:2] + (F,), 1, 2)
+    up = ints(k2, p["w_in"].shape[:2] + (F,), -1, 1)
+    return dict(p, w_in=jnp.concatenate([gate, up], -1),
+                w_out=ints(k3, p["w_out"].shape, -1, 1))
+
+
+@functools.lru_cache(maxsize=None)
+def _held_programs(first):
+    """``moe_held`` jitted on a layer's slice and on the stack with a
+    traced layer index, for a device holding experts [first, first + 2)."""
+    cfg = _cfg(2, first)
+    return (jax.jit(lambda p, x, v: moe_held(cfg, p, x, v)),
+            jax.jit(lambda p, x, v, i: moe_held(cfg, p, x, v, i)))
+
+
+@pytest.mark.parametrize("first", [0, 3])
+@pytest.mark.parametrize("layer", [0, 2, N_LAYERS - 1])
+@pytest.mark.parametrize("routing", ["drawn", "none_here"])
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_stacked_experts_match_the_layer_slice(kind, routing, layer, first):
+    """Given the held experts of every period layer stacked and a layer's
+    index (traced, as the serving programs' layer loop passes it), the
+    layer gives what it gives on that layer's slice: the grouped matmuls
+    read the same rows against the same experts, and the other layers'
+    groups are empty. ``none_here``: every token chooses only experts held
+    elsewhere.
+
+    Bit for bit on whole-number inputs and weights, where every sum is
+    exact: on the CPU jax's ragged dot is one dense contraction over the
+    groups and the features together, so the empty groups reorder its
+    float sums. On drawn weights the two agree to float32 rounding."""
+    layers = [_share(_params(_cfg(), seed=10 + i), first, 2)
+              for i in range(N_LAYERS)]
+    if routing == "none_here":      # the held experts' logits sink
+        layers = [dict(p, router=p["router"].at[:, first:first + 2].add(
+            -50.0 / SMOKE.d_model ** 0.5)) for p in layers]
+    # positive features for the sunk logits to hold; centred ones route
+    # the drawn tokens over every expert
+    x, valid = _rows(kind, 2.0 if routing == "none_here" else 0.0)
+    sliced, stacked = _held_programs(first)
+
+    def both(layers, x):
+        stack = dict(layers[layer],
+                     w_in=jnp.stack([p["w_in"] for p in layers]),
+                     w_out=jnp.stack([p["w_out"] for p in layers]))
+        y, _, c = sliced(layers[layer], x, valid)
+        ys, _, cs = stacked(stack, x, valid, jnp.asarray(layer))
+        assert {k: int(v) for k, v in cs.items()} == \
+            {k: int(v) for k, v in c.items()}
+        return np.asarray(ys), np.asarray(y), cs
+
+    ys, y, _ = both([_whole(p, 20 + i) for i, p in enumerate(layers)],
+                    jnp.clip(jnp.round(1.5 + 0.5 * x), 1, 2))
+    np.testing.assert_array_equal(ys, y)
+    ys, y, counts = both(layers, x)
+    np.testing.assert_allclose(ys, y, rtol=1e-6, atol=1e-6)
+    if routing == "none_here":
+        assert int(counts["assignments_here"]) == 0
+    else:
+        assert int(counts["assignments_here"]) > 0
+
+
 def test_yarn_frequencies_and_scale():
     """DeepSeek-V2-Lite's YaRN at 64 rope dims: pairs 0-10 keep theta's
     frequency, pairs 23-31 are divided by 40, a ramp joins them; the
@@ -193,3 +279,56 @@ def test_decode_layer_counts_only_occupied_rows(where):
         return
     assert int(counts["assignments_here"]) == 2 * cfg.moe.top_k
     assert 1 <= int(counts["experts_touched"]) <= 2 * cfg.moe.top_k
+
+
+def _scans(jaxpr):
+    """Every scan equation in a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+def test_training_slices_each_layers_experts():
+    """``train_logits`` keeps cutting each layer's held experts out of the
+    stacks as the layer loop's scanned input: in its gradient's program the
+    (L, E, D, 2F) ``w_in`` stack enters every layer loop, forward and
+    backward, as a scanned input, and its gradient leaves as a scanned
+    output, never a loop constant or carried value (a carried stack would
+    sum a whole-stack gradient on every layer). The gradient equals the
+    one through the unrolled layer loop's static slices."""
+    from repro.models import model_defs
+    from repro.models import transformer as T
+    cfg = replace(get_config("deepseek-v2-lite-ep8", smoke=True),
+                  dtype="float32")
+    params = init_params(model_defs(cfg), jax.random.PRNGKey(9))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(10), (2, 12), 0,
+                                          cfg.vocab_size)}
+    stack = params["period"][0]["ffn"]["w_in"].shape
+    assert stack[:2] == (cfg.n_periods, cfg.moe.n_held) and cfg.n_periods > 1
+
+    def grad_w_in(p, flags):
+        def loss(p):
+            logits, _ = T.train_logits(cfg, p, batch, flags=flags)
+            return jnp.mean(jax.nn.log_softmax(logits) ** 2)
+        return jax.grad(loss)(p)["period"][0]["ffn"]["w_in"]
+
+    jaxpr = jax.make_jaxpr(lambda p: grad_w_in(p, T.RunFlags()))(
+        params).jaxpr
+    scans = list(_scans(jaxpr))
+    scanned_in = scanned_out = 0
+    for eqn in scans:
+        n_c, n_k = eqn.params["num_consts"], eqn.params["num_carry"]
+        shapes = [v.aval.shape for v in eqn.invars]
+        assert stack not in shapes[:n_c + n_k]
+        assert stack not in [v.aval.shape for v in eqn.outvars[:n_k]]
+        scanned_in += stack in shapes[n_c + n_k:]
+        scanned_out += stack in [v.aval.shape for v in eqn.outvars[n_k:]]
+    assert scanned_in >= 2 and scanned_out >= 1
+    grad = jax.jit(grad_w_in, static_argnums=1)
+    got = grad(params, T.RunFlags())
+    want = grad(params, T.RunFlags(unroll_layers=True, remat="none"))
+    assert float(jnp.abs(want).max()) > 0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-7)

@@ -8,6 +8,7 @@ holds at a time, so it happens only inside the module fixture below, and
 all such compiles stay in this one file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import autotune, ops
 from repro.models import abstract_params, model_defs
-from repro.models.transformer import init_cache
+from repro.models import transformer as T
+from repro.models.transformer import init_cache, prefill
 from repro.parallel.decode_attn import paged_decode_attention
 from repro.serve.engine import decode_program, split_cache
 
@@ -176,20 +178,74 @@ def test_held_expert_layer_compiles_for_v5e(one_chip):
         assert c.memory_analysis().temp_size_in_bytes < dense
 
 
+# a layer's 8 held experts copied out of the (26, 8, ...) stacks
+_EXPERT_COPY = re.compile(r"= bf16\[8,(2048,2816|1408,2048)\]")
+_GROUPED_ROWS = re.compile(r"%ragged-dot-none[.\d]* = bf16\[(\d+),")
+
+
+def _docs_programs(sharding):
+    """The DeepSeek-V2-Lite share at the docs cell's full size (27 layers,
+    16 rows of 4096 positions, one 4096-long prefill row), with its MoE
+    counts: ``{name: (program, args)}`` for ServeEngine's decode and
+    prefill programs."""
+    cfg = get_config("deepseek-v2-lite-ep8")
+    params, cache, tokens = _decode_args(cfg, 16, 4096, sharding)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return cfg, cache, {
+        "decode": (decode_program(cfg, with_counts=True),
+                   (params, *split_cache(cfg, cache), tokens)),
+        "prefill": (jax.jit(lambda p, b, n: prefill(cfg, p, b, n,
+                                                    with_counts=True)),
+                    (params, {"tokens": spec((1, 4096))}, spec((1,))))}
+
+
+def _experts_sliced_per_layer(monkeypatch):
+    """Serve as a layer loop that slices each layer's held experts out of
+    the stacks, as training does."""
+    monkeypatch.setattr(T, "held_stacks", lambda cfg, period, flags: (
+        period, (None,) * len(cfg.period)))
+
+
+@pytest.mark.parametrize("name,rows", [("decode", 16 * 6),
+                                       ("prefill", 4096 * 6)])
+def test_yarn_mla_serving_reads_held_experts_in_place_for_v5e(
+        one_chip, monkeypatch, name, rows):
+    """The docs cell's decode and prefill programs hand the TPU's ragged-dot
+    kernel the whole (26·8, d, ·) expert stacks: no op copies a layer's 8
+    held experts out of them (sliced per layer, the program does, since the
+    kernel is a custom call that fuses no slice), the grouped matmuls keep
+    their names and row counts (16 or 4096 rows times 6 experts per token),
+    and the temporaries are no larger than the sliced form's."""
+    _, _, programs = _docs_programs(one_chip)
+    program, args = programs[name]
+    c = program.lower(*args).compile()
+    text = c.as_text()
+    assert not _EXPERT_COPY.search(text)
+    assert set(_GROUPED_ROWS.findall(text)) == {str(rows)}
+    _experts_sliced_per_layer(monkeypatch)
+    program, args = _docs_programs(one_chip)[2][name]    # traced anew
+    sliced = program.lower(*args).compile()
+    assert _EXPERT_COPY.search(sliced.as_text())
+    assert c.memory_analysis().temp_size_in_bytes <= \
+        sliced.memory_analysis().temp_size_in_bytes
+
+
 def test_yarn_mla_decode_compiles_for_v5e(one_chip):
     """ServeEngine's decode program of the DeepSeek-V2-Lite share, YaRN on,
-    with its MoE counts, at the cell's widths and 16 rows of 4096 positions,
-    depth cut to the dense layer and one MoE layer: it compiles, donates
-    nothing (MLA latents are replaced whole), and its temporaries stay
-    under a tenth of the latent cache it rewrites."""
-    cfg = dataclasses.replace(get_config("deepseek-v2-lite-ep8"), n_layers=2)
-    params, cache, tokens = _decode_args(cfg, 16, 4096, one_chip)
-    owned, kept = split_cache(cfg, cache)
-    c = decode_program(cfg, with_counts=True).lower(
-        params, owned, kept, tokens).compile()
+    with its MoE counts, at the cell's full size, 16 rows of 4096
+    positions: it compiles, donates nothing (MLA latents are replaced
+    whole), its temporaries stay under a tenth of the latent cache it
+    rewrites, and no op copies a layer's held experts."""
+    cfg, cache, programs = _docs_programs(one_chip)
+    program, args = programs["decode"]
+    c = program.lower(*args).compile()
     mem = c.memory_analysis()
     latents = _nbytes(cache["period"]) + _nbytes(cache["prelayers"])
-    assert latents == 2 * 16 * 4096 * (512 + 64) * 2
+    assert latents == cfg.n_layers * 16 * 4096 * (512 + 64) * 2
     assert mem.alias_size_in_bytes == 0
     assert mem.temp_size_in_bytes < latents / 10
     assert "ragged-dot" in c.as_text()
+    assert not _EXPERT_COPY.search(c.as_text())
