@@ -42,7 +42,7 @@ from roofline import TARGET_KIND, chip_peaks               # noqa: E402
 from repro.kernels import autotune, ops, ref                # noqa: E402
 from repro.models.attention import (                        # noqa: E402
     decode_attention_ref, write_kv_cache)
-from repro.parallel.decode_attn import (                    # noqa: E402
+from repro.serve.paged import (                             # noqa: E402
     paged_decode_attention, paged_write_kv, PagedKVCache)
 
 REPO_ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)),

@@ -1,12 +1,12 @@
 """GQA attention: projections, RoPE, flash-style chunked attention (XLA path),
-Pallas-kernel dispatch, and KV-cache decode (single-device oracle here; the
-sequence-sharded distributed decode lives in ``repro.parallel.decode_attn``).
+Pallas-kernel dispatch, and KV-cache decode: the single-shard write and
+attend live here, and the sequence-sharded decode they hand over to when the
+mesh splits the sequence lives in ``repro.parallel.decode_attn``.
 
-The XLA path implements online-softmax over unrolled (q-chunk × kv-chunk)
-tiles so that (a) 32k prefill never materializes an S×S score matrix and
-(b) per-tile FLOPs appear un-hidden in the compiled HLO (no inner scan), which
-keeps ``cost_analysis`` honest. Causal tile-skipping is static: above-diagonal
-tiles are never emitted.
+The XLA path implements online-softmax over (q-chunk × kv-chunk) tiles, so
+32k prefill never materializes an S×S score matrix: the q-chunks are
+unrolled, the kv-tiles of each are a ``lax.scan``. Causal tile-skipping is
+static: above-diagonal tiles are never emitted.
 """
 from __future__ import annotations
 
@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDef
-from repro.models.layers import apply_rope, rms_head_norm
+from repro.models.layers import apply_rope, rms_head_norm, scan_unroll
+from repro.parallel.decode_attn import live_seq_axes, sharded_decode_attention
 
 NEG_INF = -1e30
 
@@ -103,9 +104,9 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
     are statically skipped (per q-tile the kv scan covers only the causal
     prefix). ``q_offset`` is the absolute position of q[0].
 
-    The kv-tile loop is a ``lax.scan`` by default (one tile of temp memory);
-    ``unroll=True`` emits the tiles as straight-line ops so the dry-run's
-    roofline variants get true FLOP counts (scan bodies are counted once).
+    The kv-tile loop is a ``lax.scan`` (one tile of temp memory);
+    ``unroll=True`` unrolls it so the dry-run's roofline variants get true
+    FLOP counts (a rolled scan's body is counted once).
     ``scale`` multiplies the scores (default ``HD ** -0.5``).
     """
     B, Sq, H, HD = q.shape
@@ -157,22 +158,14 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
         m = jnp.full((B, H, cq), NEG_INF, jnp.float32)
         l = jnp.zeros((B, H, cq), jnp.float32)
         acc = jnp.zeros((B, H, cq, HD), jnp.float32)
-        if unroll:
-            for ki in range(nk_q):
-                k_blk = jax.lax.slice_in_dim(k, ki * ck, (ki + 1) * ck,
-                                             axis=1)
-                v_blk = jax.lax.slice_in_dim(v, ki * ck, (ki + 1) * ck,
-                                             axis=1)
-                m, l, acc = tile(q_blk, q_lo, (m, l, acc), ki * ck, k_blk,
-                                 v_blk)
-        else:
-            def body(carry, ki):
-                k_blk = jax.lax.dynamic_slice_in_dim(k, ki * ck, ck, 1)
-                v_blk = jax.lax.dynamic_slice_in_dim(v, ki * ck, ck, 1)
-                return tile(q_blk, q_lo, carry, ki * ck, k_blk, v_blk), None
 
-            (m, l, acc), _ = jax.lax.scan(body, (m, l, acc),
-                                          jnp.arange(nk_q))
+        def body(carry, ki):
+            k_blk = jax.lax.dynamic_slice_in_dim(k, ki * ck, ck, 1)
+            v_blk = jax.lax.dynamic_slice_in_dim(v, ki * ck, ck, 1)
+            return tile(q_blk, q_lo, carry, ki * ck, k_blk, v_blk), None
+
+        (m, l, acc), _ = jax.lax.scan(body, (m, l, acc), jnp.arange(nk_q),
+                                      unroll=scan_unroll(unroll, nk_q))
         o = acc / jnp.maximum(l[..., None], 1e-30)
         outs.append(o.astype(q.dtype))
     o = jnp.concatenate(outs, axis=2) if len(outs) > 1 else outs[0]
@@ -212,7 +205,8 @@ def self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Decode (single-device oracle). Distributed version: repro.parallel.decode_attn
+# Decode: write the new token, then attend. Sequence-sharded version:
+# repro.parallel.decode_attn
 # ---------------------------------------------------------------------------
 
 def decode_attention_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -263,31 +257,34 @@ def write_kv_token(cache: jax.Array, new: jax.Array, lengths: jax.Array,
 def decode_self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
                           cache: Dict, lengths: jax.Array, *,
                           layer=None,
-                          seq_axes: Optional[Tuple[str, ...]] = None,
+                          seq_axes: Tuple[str, ...] = (),
                           batch_axes: Tuple[str, ...] = (),
                           ) -> Tuple[jax.Array, Dict]:
     """One decode step. x: (B, 1, D). cache: {"k": (B,S,KV,HD), "v": ...}.
     ``lengths`` counts tokens already in the cache (new token goes at index
     lengths, and attends to itself).
 
-    With ``layer`` (single shard only), ``cache`` holds every layer's keys
-    and values stacked, (L,B,S,KV,HD): the token is written at ``[layer]``
-    with ``write_kv_token``, that layer attends, and the whole stack is
-    returned for the caller's layer loop to carry. The arithmetic is
-    ``write_kv_cache`` then ``decode_attention_ref``, bit for bit."""
+    On one shard the token is written (``write_kv_cache``) and the layer
+    attends (``decode_attention_ref``). With ``layer``, ``cache`` holds
+    every layer's keys and values stacked, (L,B,S,KV,HD): the token is
+    written at ``[layer]`` with ``write_kv_token``, that layer attends, and
+    the whole stack is returned for the caller's layer loop to carry, bit
+    for bit what the per-layer write computes. When the mesh splits the
+    sequence over ``seq_axes`` (``live_seq_axes``), the sharded decode
+    writes and attends instead."""
     q, k, v = project_qkv(cfg, p, x, lengths[:, None])
     q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]
+    seq_axes = live_seq_axes(seq_axes)
     if seq_axes:
-        from repro.parallel.decode_attn import sharded_decode_attention
         o, kc, vc = sharded_decode_attention(
             q1, cache["k"], cache["v"], k1, v1, lengths, seq_axes=seq_axes,
             batch_axes=batch_axes)
-    elif layer is not None:
+    elif layer is None:
+        kc, vc = write_kv_cache(cache["k"], cache["v"], k1, v1, lengths)
+        o = decode_attention_ref(q1, kc, vc, lengths + 1)
+    else:
         kc = write_kv_token(cache["k"], k1, lengths, layer)
         vc = write_kv_token(cache["v"], v1, lengths, layer)
         o = decode_attention_ref(q1, kc[layer], vc[layer], lengths + 1)
-    else:
-        kc, vc = write_kv_cache(cache["k"], cache["v"], k1, v1, lengths)
-        o = decode_attention_ref(q1, kc, vc, lengths + 1)
     y = output_proj(cfg, p, o[:, None])
     return y, {"k": kc, "v": vc}

@@ -16,6 +16,13 @@ from repro.configs.base import ModelConfig, YarnConfig
 from repro.models.params import ParamDef
 
 
+def scan_unroll(unroll: bool, length: int) -> int:
+    """``lax.scan``'s ``unroll=`` for an unroll flag over ``length`` trips:
+    1 keeps the loop; ``length + 1`` inlines every trip (``unroll=True``
+    would leave a one-trip scan a loop)."""
+    return length + 1 if unroll else 1
+
+
 # ---------------------------------------------------------------------------
 # Norms
 # ---------------------------------------------------------------------------

@@ -4,7 +4,9 @@ Prefill caches only the compressed latent ``c_kv`` (kv_lora_rank) plus the
 shared rope key (qk_rope_head_dim) per token. Decode uses the *absorbed* form:
 W_uk is folded into the query and W_uv into the output so attention runs
 directly in the latent space — per-step work is O(S · (R + DR)) per head
-instead of reconstructing 128 full heads of K/V.
+instead of reconstructing 128 full heads of K/V. On one shard a decode step
+is ``write_latent_token`` then ``latent_attention``; when the mesh splits the
+sequence, ``repro.parallel.decode_attn.sharded_mla_decode`` does both.
 """
 from __future__ import annotations
 
@@ -17,7 +19,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDef
 from repro.models.layers import apply_rope, yarn_mscale
-from repro.models.attention import flash_attention_xla
+from repro.models.attention import NEG_INF, flash_attention_xla
+from repro.parallel.decode_attn import live_seq_axes, sharded_mla_decode
 
 
 def mla_defs(cfg: ModelConfig) -> Dict[str, ParamDef]:
@@ -118,55 +121,72 @@ def v_pad(v: jax.Array, d: int) -> jax.Array:
     return jnp.pad(v, pad)
 
 
+def write_latent_token(cache: jax.Array, new: jax.Array,
+                       lengths: jax.Array) -> jax.Array:
+    """Insert one new latent per sequence at its current length:
+    ``cache[b, lengths[b]] = new[b]``. cache: (B, S, W); new: (B, W). A
+    length at or past S writes position S-1."""
+    B, S = cache.shape[:2]
+
+    def row(cache_row, new_row, idx, in_range):
+        upd = jax.lax.dynamic_update_slice_in_dim(
+            cache_row, new_row[None].astype(cache_row.dtype), idx, axis=0)
+        return jnp.where(in_range, upd, cache_row)
+
+    return jax.vmap(row)(cache, new, jnp.clip(lengths, 0, S - 1),
+                         jnp.ones((B, 1), bool))
+
+
+def latent_attention(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array,
+                     kr: jax.Array, lengths: jax.Array,
+                     sm_scale: float) -> jax.Array:
+    """Absorbed MLA attention over one shard's latents. q_lat: (B, H, R),
+    q_nope absorbed through W_uk; q_rope: (B, H, DR); ckv: (B, S, R); kr:
+    (B, S, DR), the rope key shared across heads. ``lengths`` counts the
+    tokens before this step's, which sits at position ``lengths`` and
+    attends to itself. Returns the latent context (B, H, R); the caller
+    applies W_uv."""
+    s = (jnp.einsum("bhr,bsr->bhs", q_lat, ckv,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhd,bsd->bhs", q_rope, kr,
+                      preferred_element_type=jnp.float32)) * sm_scale
+    kpos = jnp.arange(ckv.shape[1])
+    s = jnp.where(kpos[None, None, :] < (lengths + 1)[:, None, None], s,
+                  NEG_INF)
+    w = jax.nn.softmax(s, -1)
+    ctx = jnp.einsum("bhs,bsr->bhr", w.astype(ckv.dtype), ckv,
+                     preferred_element_type=jnp.float32)
+    return ctx.astype(q_lat.dtype)
+
+
 def mla_decode_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
                          cache: Dict, lengths: jax.Array, *,
-                         seq_axes: Optional[Tuple[str, ...]] = None,
-                         batch_axes: Tuple[str, ...] = ("data",),
-                         absorbed: bool = True) -> Tuple[jax.Array, Dict]:
+                         seq_axes: Tuple[str, ...] = (),
+                         batch_axes: Tuple[str, ...] = ("data",)
+                         ) -> Tuple[jax.Array, Dict]:
     """One decode step, absorbed form. x: (B,1,D);
-    cache = {"ckv": (B,S,R), "kr": (B,S,dr)}."""
-    m = cfg.mla
-    H = cfg.n_heads
-    dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+    cache = {"ckv": (B,S,R), "kr": (B,S,dr)}. ``lengths`` counts tokens
+    already in the cache."""
+    dn = cfg.mla.qk_nope_head_dim
     dt = x.dtype
     sm_scale = softmax_scale(cfg)
     q_nope, q_rope = _project_q(cfg, p, x, lengths[:, None])
     ckv_new, kr_new = _project_kv_latent(cfg, p, x, lengths[:, None])
     w_uk = p["w_ukv"].astype(dt)[..., :dn]              # (R, H, dn)
     w_uv = p["w_ukv"].astype(dt)[..., dn:]              # (R, H, dv)
-
-    if not absorbed:
-        # naive oracle: write latents, reconstruct all K/V, full softmax
-        from repro.models.attention import NEG_INF as NI
-        B = x.shape[0]
-        S = cache["ckv"].shape[1]
-        pos = jnp.clip(lengths, 0, S - 1)
-        ckv = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-            c, n, i, axis=0))(cache["ckv"], ckv_new, pos)
-        kr = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
-            c, n, i, axis=0))(cache["kr"], kr_new, pos)
-        kv = jnp.einsum("bsr,rhd->bshd", ckv, p["w_ukv"].astype(dt))
-        k_nope, v = kv[..., :dn], kv[..., dn:]
-        q = jnp.concatenate([q_nope, q_rope], -1)[:, 0]          # (B,H,dn+dr)
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(
-            kr[:, :, None, :], k_nope.shape[:3] + (dr,))], -1)
-        s = jnp.einsum("bhd,bshd->bhs", q, k,
-                       preferred_element_type=jnp.float32) * sm_scale
-        kpos = jnp.arange(S)
-        s = jnp.where(kpos[None, None, :] < (lengths + 1)[:, None, None], s, NI)
-        w = jax.nn.softmax(s, -1)
-        o = jnp.einsum("bhs,bshd->bhd", w.astype(dt), v,
-                       preferred_element_type=jnp.float32).astype(dt)
-        y = jnp.einsum("bhd,hdD->bD", o, p["w_o"].astype(dt))[:, None]
-        return y, {"ckv": ckv, "kr": kr}
-
-    # absorbed: q_lat = q_nope @ W_uk  -> attention in latent space
+    # q_lat = q_nope @ W_uk  -> attention in latent space
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)       # (B,H,R)
-    from repro.parallel.decode_attn import sharded_mla_decode
-    ctx, ckv, kr = sharded_mla_decode(
-        q_lat, q_rope[:, 0], cache["ckv"], cache["kr"], ckv_new[:, 0],
-        kr_new[:, 0], lengths, sm_scale=sm_scale,
-        seq_axes=seq_axes or (), batch_axes=batch_axes)
+    q_rope, ckv_new, kr_new = q_rope[:, 0], ckv_new[:, 0], kr_new[:, 0]
+    seq_axes = live_seq_axes(seq_axes)
+    if seq_axes:
+        ctx, ckv, kr = sharded_mla_decode(
+            q_lat, q_rope, cache["ckv"], cache["kr"], ckv_new, kr_new,
+            lengths, sm_scale=sm_scale, seq_axes=seq_axes,
+            batch_axes=batch_axes)
+    else:
+        ckv = write_latent_token(cache["ckv"], ckv_new, lengths)
+        kr = write_latent_token(cache["kr"], kr_new, lengths)
+        ctx = latent_attention(q_lat, q_rope, ckv, kr, lengths, sm_scale)
     o = jnp.einsum("bhr,rhd->bhd", ctx.astype(dt), w_uv)         # (B,H,dv)
     y = jnp.einsum("bhd,hdD->bD", o, p["w_o"].astype(dt))[:, None]
     return y, {"ckv": ckv, "kr": kr}

@@ -35,11 +35,9 @@ class RunFlags:
     decode_seq_axes: Tuple[str, ...] = ()  # () -> single-shard reference path
     act_spec: Optional[Any] = None         # PartitionSpec for (B,S,D) activations
     remat: str = "full"                    # full | none
-    mamba_chunks: int = 8
-    mla_absorbed: bool = True
-    # unroll the layer stack instead of lax.scan: used by the dry-run's
-    # roofline variants so cost_analysis counts every layer (scan bodies are
-    # counted once regardless of trip count)
+    # unroll the layer scan (and flash attention's kv-tile scan): used by
+    # the dry-run's roofline variants so cost_analysis counts every layer
+    # (a rolled scan's body is counted once regardless of trip count)
     unroll_layers: bool = False
     moe_combine: str = "psum"              # psum | allgather (§Perf)
     # cast weight matrices to the compute dtype BEFORE their use-site, so the
@@ -154,7 +152,7 @@ def _apply_mixer_seq(cfg, spec, p, x, positions, lengths, flags, want_cache):
                  "kr": kr.astype(jnp.bfloat16)} if want_cache else None
         return y, cache
     if spec.mixer == "mamba":
-        y = MB.mamba_mixer(cfg, p, x, n_chunks=flags.mamba_chunks)
+        y = MB.mamba_mixer(cfg, p, x)
         cache = None
         if want_cache:
             lens = lengths if lengths is not None else \
@@ -229,13 +227,11 @@ def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
     if spec.mixer == "attn":
         y_mix, new_cache = A.decode_self_attention(
             cfg, p["mixer"], h, cache, lengths, layer=layer,
-            seq_axes=flags.decode_seq_axes or None,
-            batch_axes=flags.token_axes)
+            seq_axes=flags.decode_seq_axes, batch_axes=flags.token_axes)
     elif spec.mixer == "mla":
         y_mix, new_cache = MLA.mla_decode_attention(
             cfg, p["mixer"], h, cache, lengths,
-            seq_axes=flags.decode_seq_axes or None,
-            batch_axes=flags.token_axes, absorbed=flags.mla_absorbed)
+            seq_axes=flags.decode_seq_axes, batch_axes=flags.token_axes)
     elif spec.mixer == "mamba":
         y_mix, new_cache = MB.mamba_decode(cfg, p["mixer"], h, cache)
     elif spec.mixer == "mlstm":
@@ -387,34 +383,13 @@ def forward(cfg: ModelConfig, params: Dict, batch: Dict[str, jax.Array], *,
     body = period_body
     if flags.remat == "full":
         body = jax.remat(period_body)
-    if flags.unroll_layers:
-        cache_list, count_list = [], []
-        carry = (x, aux)
-        for i in range(cfg.n_periods):
-            pp = jax.tree.map(lambda a: a[i], period)
-            carry, caches = body(carry, (pp, i) if stacked else pp)
-            if with_counts:
-                caches, counts = caches
-                count_list.append(counts)
-            cache_list.append(caches)
-        (x, aux) = carry
-        period_counts = (jax.tree.map(lambda *xs: jnp.stack(xs), *count_list)
-                         if count_list else ())
-        period_caches = None
-        if want_cache:
-            if cache_list:
-                period_caches = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                             *cache_list)
-            else:            # zero-period variant lowers
-                period_caches = tuple(
-                    jax.tree.map(lambda a: jnp.zeros((0,) + a.shape, a.dtype),
-                                 layer_cache(cfg, s, x.shape[0], x.shape[1]))
-                    for s in cfg.period)
-    else:
-        xs = (period, jnp.arange(cfg.n_periods)) if stacked else period
-        (x, aux), period_caches = jax.lax.scan(body, (x, aux), xs)
-        if with_counts:
-            period_caches, period_counts = period_caches
+    xs = (period, jnp.arange(cfg.n_periods)) if stacked else period
+    (x, aux), period_caches = jax.lax.scan(
+        body, (x, aux), xs,
+        unroll=L.scan_unroll(flags.unroll_layers, cfg.n_periods))
+    period_counts = ()
+    if with_counts:
+        period_caches, period_counts = period_caches
     x = L.apply_norm(cfg, params["out_norm"], x)
     caches = None
     if want_cache:
@@ -512,27 +487,13 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, *,
             return (x, tuple(kv)), (tuple(st), tuple(counts))
         return (x, tuple(kv)), tuple(st)
 
+    (x, kv), state = jax.lax.scan(
+        body, (x, kv),
+        (period, state, jnp.arange(cfg.n_periods)),
+        unroll=L.scan_unroll(flags.unroll_layers, cfg.n_periods))
     period_counts = ()
-    if flags.unroll_layers:
-        new_list, count_list = [], []
-        for i in range(cfg.n_periods):
-            pp, st = jax.tree.map(lambda a: a[i], (period, state))
-            (x, kv), st = body((x, kv), (pp, st, i))
-            if with_counts:
-                st, counts = st
-                count_list.append(counts)
-            new_list.append(st)
-        if new_list:         # else the zero-period variant keeps its state
-            state = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
-        if count_list:
-            period_counts = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                         *count_list)
-    else:
-        (x, kv), state = jax.lax.scan(
-            body, (x, kv),
-            (period, state, jnp.arange(cfg.n_periods)))
-        if with_counts:
-            state, period_counts = state
+    if with_counts:
+        state, period_counts = state
     new_period = tuple(c if k else s
                        for c, s, k in zip(kv, state, carried))
     x = L.apply_norm(cfg, params["out_norm"], x)

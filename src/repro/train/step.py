@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.compat import NamedSharding, P
 from repro.configs.base import ModelConfig
 from repro.models import model_defs, init_params
+from repro.models.layers import scan_unroll
 from repro.models.transformer import RunFlags, train_logits
 from repro.train.loss import cross_entropy
 from repro.train.optimizer import OptConfig, adamw_update, init_opt
@@ -28,8 +29,8 @@ class TrainConfig:
     accum_dtype: Any = jnp.float32
     z_loss: float = 1e-4
     aux_scale: float = 1.0        # scale on MoE aux losses
-    # python-loop accumulation instead of lax.scan (dry-run roofline variants:
-    # unrolled microbatches are counted correctly by cost_analysis)
+    # unroll the accumulation scan, even over one microbatch (dry-run
+    # roofline variants: cost_analysis counts a rolled scan's body once)
     unroll_accum: bool = False
 
 
@@ -72,22 +73,7 @@ def build_train_step(cfg: ModelConfig, ocfg: OptConfig,
     def train_step(state, batch):
         params, opt = state["params"], state["opt"]
         m = tcfg.n_microbatches
-        if tcfg.unroll_accum:
-            micros = _split_micro(batch, m)
-            grads = None
-            stats = None
-            for i in range(m):
-                micro = jax.tree.map(lambda a: a[i], micros)
-                g, s = grad_fn(params, micro)
-                g = jax.tree.map(lambda a: a.astype(tcfg.accum_dtype), g)
-                grads = g if grads is None else jax.tree.map(
-                    lambda a, b: a + b, grads, g)
-                stats = s if stats is None else jax.tree.map(
-                    lambda a, b: a + b, stats, s)
-            grads = jax.tree.map(lambda g: (g / m).astype(jnp.float32), grads)
-            stats = jax.tree.map(lambda s: s / m, stats)
-            stats["tokens"] = stats["tokens"] * m
-        elif m > 1:
+        if m > 1 or tcfg.unroll_accum:
             micros = _split_micro(batch, m)
 
             def acc_body(carry, micro):
@@ -103,7 +89,9 @@ def build_train_step(cfg: ModelConfig, ocfg: OptConfig,
             zero_s = {k: jnp.zeros((), jnp.float32) for k in
                       ("ce", "z_loss", "accuracy", "tokens", "loss",
                        "moe_load_balance", "moe_router_z")}
-            (grads, stats), _ = jax.lax.scan(acc_body, (zero_g, zero_s), micros)
+            (grads, stats), _ = jax.lax.scan(
+                acc_body, (zero_g, zero_s), micros,
+                unroll=scan_unroll(tcfg.unroll_accum, m))
             grads = jax.tree.map(lambda g: (g / m).astype(jnp.float32), grads)
             stats = jax.tree.map(lambda s: s / m, stats)
             stats["tokens"] = stats["tokens"] * m

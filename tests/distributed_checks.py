@@ -159,6 +159,7 @@ def check_sharded_decode_attention():
 
 def check_sharded_mla_decode():
     import math
+    from repro.models.mla import latent_attention, write_latent_token
     from repro.parallel.decode_attn import sharded_mla_decode
     mesh = mesh24()
     B, S, H, R, DR = 2, 16, 4, 8, 4
@@ -172,8 +173,8 @@ def check_sharded_mla_decode():
     kr_n = jax.random.normal(ks[5], (B, DR), jnp.float32)
     lens = jnp.asarray([5, 11], jnp.int32)
     scale = 1.0 / math.sqrt(R + DR)
-    ref, _, _ = sharded_mla_decode(q_lat, q_rope, ckv, kr, ckv_n, kr_n, lens,
-                                   sm_scale=scale, seq_axes=())
+    ref = latent_attention(q_lat, q_rope, write_latent_token(ckv, ckv_n, lens),
+                           write_latent_token(kr, kr_n, lens), lens, scale)
     with compat.set_mesh(mesh):
         put = lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec))
         o, _, _ = jax.jit(lambda *a: sharded_mla_decode(

@@ -1,5 +1,6 @@
 """Attention equivalences: chunked online-softmax (XLA flash path) vs naive
-softmax; GQA decode reference; MLA absorbed vs naive decode."""
+softmax; GQA decode reference; MLA absorbed vs naive decode (the naive
+form lives here, as the reference)."""
 import math
 
 import jax
@@ -9,9 +10,10 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels.ref import attention_ref
-from repro.models.attention import (decode_attention_ref, flash_attention_xla,
-                                    repeat_kv, write_kv_cache)
-from repro.models.mla import mla_decode_attention
+from repro.models.attention import (NEG_INF, decode_attention_ref,
+                                    flash_attention_xla, repeat_kv,
+                                    write_kv_cache)
+from repro.models import mla as MLA
 from repro.models import model_defs, init_params
 
 # ~42s of wall time: excluded from the default tier-1 run (pytest.ini
@@ -81,6 +83,36 @@ def test_write_kv_cache_positions():
     assert float(kc[1, 5].sum()) == KV * D and float(vc[1, 5].sum()) == 2 * KV * D
 
 
+def naive_mla_decode(cfg, p, x, cache, lengths):
+    """MLA decode without absorption: write the latents, rebuild every
+    head's K/V from them, full softmax."""
+    dn, dr = cfg.mla.qk_nope_head_dim, cfg.mla.qk_rope_head_dim
+    dt = x.dtype
+    q_nope, q_rope = MLA._project_q(cfg, p, x, lengths[:, None])
+    ckv_new, kr_new = MLA._project_kv_latent(cfg, p, x, lengths[:, None])
+    S = cache["ckv"].shape[1]
+    pos = jnp.clip(lengths, 0, S - 1)
+    ckv = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
+        c, n, i, axis=0))(cache["ckv"], ckv_new, pos)
+    kr = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
+        c, n, i, axis=0))(cache["kr"], kr_new, pos)
+    kv = jnp.einsum("bsr,rhd->bshd", ckv, p["w_ukv"].astype(dt))
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    q = jnp.concatenate([q_nope, q_rope], -1)[:, 0]          # (B,H,dn+dr)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        kr[:, :, None, :], k_nope.shape[:3] + (dr,))], -1)
+    s = jnp.einsum("bhd,bshd->bhs", q, k,
+                   preferred_element_type=jnp.float32) * MLA.softmax_scale(cfg)
+    kpos = jnp.arange(S)
+    s = jnp.where(kpos[None, None, :] < (lengths + 1)[:, None, None], s,
+                  NEG_INF)
+    w = jax.nn.softmax(s, -1)
+    o = jnp.einsum("bhs,bshd->bhd", w.astype(dt), v,
+                   preferred_element_type=jnp.float32).astype(dt)
+    y = jnp.einsum("bhd,hdD->bD", o, p["w_o"].astype(dt))[:, None]
+    return y, {"ckv": ckv, "kr": kr}
+
+
 def test_mla_absorbed_matches_naive_decode():
     cfg = get_config("deepseek-v2-236b", smoke=True)
     params = init_params(model_defs(cfg), jax.random.PRNGKey(0))
@@ -93,10 +125,8 @@ def test_mla_absorbed_matches_naive_decode():
              "kr": jax.random.normal(key, (B, S, m.qk_rope_head_dim),
                                      jnp.float32)}
     lens = jnp.asarray([5, 9], jnp.int32)
-    y_abs, c_abs = mla_decode_attention(cfg, p, x, dict(cache), lens,
-                                        absorbed=True)
-    y_naive, c_naive = mla_decode_attention(cfg, p, x, dict(cache), lens,
-                                            absorbed=False)
+    y_abs, c_abs = MLA.mla_decode_attention(cfg, p, x, dict(cache), lens)
+    y_naive, c_naive = naive_mla_decode(cfg, p, x, dict(cache), lens)
     np.testing.assert_allclose(np.asarray(y_abs), np.asarray(y_naive),
                                atol=1e-4, rtol=1e-3)
     np.testing.assert_allclose(np.asarray(c_abs["ckv"]),

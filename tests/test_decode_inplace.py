@@ -51,16 +51,8 @@ def oracle_decode_step(cfg, params, cache, tokens, unroll=False,
             counts.append(n)
         return x, (tuple(new), tuple(counts))
 
-    if unroll:
-        outs = []
-        for i in range(cfg.n_periods):
-            x, c = body(x, jax.tree.map(
-                lambda a: a[i], (params["period"], cache["period"])))
-            outs.append(c)
-        period, counts = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-    else:
-        x, (period, counts) = jax.lax.scan(
-            body, x, (params["period"], cache["period"]))
+    x, (period, counts) = jax.lax.scan(
+        body, x, (params["period"], cache["period"]), unroll=unroll)
     x = L.apply_norm(cfg, params["out_norm"], x)
     logits = L.unembed(cfg, params["embed"], x[:, 0])
     new_cache = {"prelayers": tuple(pre), "period": period,

@@ -18,9 +18,8 @@ import pytest
 from repro.kernels import autotune, ops, ref
 from repro.kernels.flash_attention import flash_attention_tpu
 from repro.models.attention import decode_attention_ref, write_kv_cache
-from repro.parallel.decode_attn import (PagedKVCache, gather_paged_kv,
-                                        paged_decode_attention,
-                                        paged_write_kv)
+from repro.serve.paged import (PagedKVCache, gather_paged_kv,
+                               paged_decode_attention, paged_write_kv)
 
 FLASH_TOL = {jnp.bfloat16: 3e-2, jnp.float32: 3e-5}
 RMSNORM_TOL = {jnp.bfloat16: 2e-2, jnp.float32: 1e-5}

@@ -78,26 +78,37 @@ def test_bench_serving_smoke_keeps_slot_invariants(model):
         out["sequential"][0].counters["decode_steps"]
 
 
-# sha256 of ``.lower(...).as_text()`` of InternLM2's two serving programs at
-# smoke size (max_batch 4, max_seq 64; jax 0.9.0), as they were before the
-# engine learned the MoE counters: a dense model's programs must not change
-# when an MoE model's do. A change that rightly alters them updates these.
-INTERNLM2_PROGRAMS = {
-    "jit_serve_prefill":
-        "6be2f4b1cf383d3f65a58c26e925d3816340865ac5e40646c2b73a15bbec9ca4",
-    "jit_serve_decode":
-        "a1b53a379198c852c4d51149980cfc6cefc28dd038d3818bd926c730933b2a2e",
+# sha256 of ``.lower(...).as_text()`` of each benchmark configuration's two
+# serving programs at smoke size (max_batch 4, max_seq 64; jax 0.9.0).
+# InternLM2's are as they were before the engine learned the MoE counters (a
+# dense model's programs must not change when an MoE model's do);
+# DeepSeek-V2-Lite-EP8's as they were before its MLA decode moved into
+# ``models/mla.py``. A change that rightly alters them updates these.
+SERVING_PROGRAMS = {
+    "internlm2-1.8b": {
+        "jit_serve_prefill":
+            "6be2f4b1cf383d3f65a58c26e925d3816340865ac5e40646c2b73a15bbec9ca4",
+        "jit_serve_decode":
+            "a1b53a379198c852c4d51149980cfc6cefc28dd038d3818bd926c730933b2a2e",
+    },
+    "deepseek-v2-lite-ep8": {
+        "jit_serve_prefill":
+            "a39e96a4fc827db08236ed3f7477bf963c52c77955279a821862d95ac95d2df1",
+        "jit_serve_decode":
+            "0e62b6d42c014d2f2ca4bedcf79603bd29e8e22461a51e23b7c38b6011b6495a",
+    },
 }
 
 
-def test_dense_serving_programs_lower_unchanged():
+@pytest.mark.parametrize("arch", sorted(SERVING_PROGRAMS))
+def test_serving_programs_lower_unchanged(arch):
     import hashlib
 
     import jax.numpy as jnp
 
     from repro.models import abstract_params
     from repro.serve.engine import split_cache
-    cfg = get_config("internlm2-1.8b", smoke=True)
+    cfg = get_config(arch, smoke=True)
     params = abstract_params(model_defs(cfg))
     eng = ServeEngine(cfg, jax.tree.map(
         lambda a: jnp.zeros(a.shape, a.dtype), params), max_batch=4,
@@ -113,7 +124,7 @@ def test_dense_serving_programs_lower_unchanged():
     for name, text in texts.items():
         assert name in text
         assert hashlib.sha256(text.encode()).hexdigest() == \
-            INTERNLM2_PROGRAMS[name], name
+            SERVING_PROGRAMS[arch][name], name
 
 
 def test_moe_counters_add_up_the_programs_counts():

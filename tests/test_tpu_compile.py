@@ -20,8 +20,8 @@ from repro.kernels import autotune, ops
 from repro.models import abstract_params, model_defs
 from repro.models import transformer as T
 from repro.models.transformer import init_cache, prefill
-from repro.parallel.decode_attn import paged_decode_attention
 from repro.serve.engine import decode_program, split_cache
+from repro.serve.paged import paged_decode_attention
 
 
 @pytest.fixture(scope="module")
