@@ -5,8 +5,10 @@ shared rope key (qk_rope_head_dim) per token. Decode uses the *absorbed* form:
 W_uk is folded into the query and W_uv into the output so attention runs
 directly in the latent space — per-step work is O(S · (R + DR)) per head
 instead of reconstructing 128 full heads of K/V. On one shard a decode step
-is ``write_latent_token`` then ``latent_attention``; when the mesh splits the
-sequence, ``repro.parallel.decode_attn.sharded_mla_decode`` does both.
+is ``write_latent_token`` then ``latent_attention``, or, inside the decode
+layer loop, ``write_kv_token`` into the carried stack of every layer's
+latents; when the mesh splits the sequence,
+``repro.parallel.decode_attn.sharded_mla_decode`` does both.
 """
 from __future__ import annotations
 
@@ -19,7 +21,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDef
 from repro.models.layers import apply_rope, yarn_mscale
-from repro.models.attention import NEG_INF, flash_attention_xla
+from repro.models.attention import NEG_INF, flash_attention_xla, write_kv_token
 from repro.parallel.decode_attn import live_seq_axes, sharded_mla_decode
 
 
@@ -161,12 +163,19 @@ def latent_attention(q_lat: jax.Array, q_rope: jax.Array, ckv: jax.Array,
 
 def mla_decode_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
                          cache: Dict, lengths: jax.Array, *,
+                         layer=None,
                          seq_axes: Tuple[str, ...] = (),
                          batch_axes: Tuple[str, ...] = ("data",)
                          ) -> Tuple[jax.Array, Dict]:
     """One decode step, absorbed form. x: (B,1,D);
     cache = {"ckv": (B,S,R), "kr": (B,S,dr)}. ``lengths`` counts tokens
-    already in the cache."""
+    already in the cache.
+
+    With ``layer``, ``cache`` holds every layer's latents stacked,
+    {"ckv": (L,B,S,R), "kr": (L,B,S,dr)}: the token is written at
+    ``[layer]`` with ``write_kv_token``, that layer attends, and the whole
+    stacks are returned for the caller's layer loop to carry, bit for bit
+    what the per-layer write computes."""
     dn = cfg.mla.qk_nope_head_dim
     dt = x.dtype
     sm_scale = softmax_scale(cfg)
@@ -183,10 +192,15 @@ def mla_decode_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
             q_lat, q_rope, cache["ckv"], cache["kr"], ckv_new, kr_new,
             lengths, sm_scale=sm_scale, seq_axes=seq_axes,
             batch_axes=batch_axes)
-    else:
+    elif layer is None:
         ckv = write_latent_token(cache["ckv"], ckv_new, lengths)
         kr = write_latent_token(cache["kr"], kr_new, lengths)
         ctx = latent_attention(q_lat, q_rope, ckv, kr, lengths, sm_scale)
+    else:
+        ckv = write_kv_token(cache["ckv"], ckv_new, lengths, layer)
+        kr = write_kv_token(cache["kr"], kr_new, lengths, layer)
+        ctx = latent_attention(q_lat, q_rope, ckv[layer], kr[layer], lengths,
+                               sm_scale)
     o = jnp.einsum("bhr,rhd->bhd", ctx.astype(dt), w_uv)         # (B,H,dv)
     y = jnp.einsum("bhd,hdD->bD", o, p["w_o"].astype(dt))[:, None]
     return y, {"ckv": ckv, "kr": kr}

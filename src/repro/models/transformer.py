@@ -230,7 +230,7 @@ def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
             seq_axes=flags.decode_seq_axes, batch_axes=flags.token_axes)
     elif spec.mixer == "mla":
         y_mix, new_cache = MLA.mla_decode_attention(
-            cfg, p["mixer"], h, cache, lengths,
+            cfg, p["mixer"], h, cache, lengths, layer=layer,
             seq_axes=flags.decode_seq_axes, batch_axes=flags.token_axes)
     elif spec.mixer == "mamba":
         y_mix, new_cache = MB.mamba_decode(cfg, p["mixer"], h, cache)
@@ -425,12 +425,13 @@ def prefill(cfg: ModelConfig, params, batch, lengths, *, flags=RunFlags(),
 def carried_layers(cfg: ModelConfig, flags: RunFlags) -> Tuple[bool, ...]:
     """For each layer of the period, whether ``decode_step`` carries its
     cache through the layer loop whole and writes it in place, one token
-    per row (``attn`` on one shard), rather than reading and replacing it
-    whole as the loop's per-layer input and output (recurrent state,
-    MLA latents, the sequence-sharded path). Only a carried cache gains
-    from being donated to the decode program: one the loop reads and
-    replaces per layer, XLA copies whole on every step if it is donated."""
-    return tuple(s.mixer == "attn" and not flags.decode_seq_axes
+    per row (attention keys and values, MLA latents, on one shard), rather
+    than reading and replacing it whole as the loop's per-layer input and
+    output (recurrent state, the sequence-sharded path). Only a carried
+    cache gains from being donated to the decode program: one the loop
+    reads and replaces per layer, XLA copies whole on every step if it is
+    donated."""
+    return tuple(s.mixer in ("attn", "mla") and not flags.decode_seq_axes
                  for s in cfg.period)
 
 

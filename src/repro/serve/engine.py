@@ -76,8 +76,8 @@ def decode_program(cfg: ModelConfig, flags: RunFlags = RunFlags(),
     new token into those buffers in place and hands them back in
     ``cache``, so the arrays passed are invalid after the call. ``kept`` is
     not: the caller may keep reading its ``lengths``, and the caches the
-    layer loop reads and replaces whole per layer would only be copied
-    whole if donated."""
+    program reads and replaces whole (recurrent state, the prelayers')
+    would only be copied whole if donated."""
     def serve_decode(p, owned, kept, t):
         period = tuple(k if o is None else o
                        for o, k in zip(owned, kept["period"]))
@@ -91,13 +91,13 @@ class ServeEngine:
     """Continuous batching over a fixed (max_batch, max_seq) cache.
 
     The decode program owns the caches it writes in place
-    (``decode_program``): each ``step`` donates ``self.cache``'s attention
-    keys and values and replaces them with the program's outputs, so an
-    array taken from ``self.cache`` before a ``step`` is invalid after it.
-    Read the cache through ``self.cache`` only. ``lengths`` stays out of
-    the donation, since ``step`` reads the lengths from before the call to
-    pin freed slots; so do recurrent state and MLA latents, which the
-    program replaces whole.
+    (``decode_program``): each ``step`` donates ``self.cache``'s period
+    attention keys and values and MLA latents and replaces them with the
+    program's outputs, so an array taken from ``self.cache`` before a
+    ``step`` is invalid after it. Read the cache through ``self.cache``
+    only. ``lengths`` stays out of the donation, since ``step`` reads the
+    lengths from before the call to pin freed slots; so do recurrent state
+    and the unscanned prelayers' caches, which the program replaces whole.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,

@@ -1,13 +1,14 @@
-"""The decode step writes each row's new key and value into the carried,
-donated cache in place, and computes exactly what writing into a per-layer
-copy and attending to it computes.
+"""The decode step writes each row's new key and value, or MLA latent and
+rope key, into the carried, donated cache in place, and computes exactly
+what writing into a per-layer copy and attending to it computes.
 
 The oracle is the decode step as the layer loop ran it before: every
 period layer's cache enters the loop as its per-layer input and leaves as
 a whole new layer, and attention is ``write_kv_cache`` then
-``decode_attention_ref`` on that layer alone. Logits and caches must agree
-bit for bit, on the scanned and the unrolled layer loop, for attention
-layers (carried) and for recurrent and MLA layers (still per-layer)."""
+``decode_attention_ref`` (MLA: ``write_latent_token`` then
+``latent_attention``) on that layer alone. Logits and caches must agree
+bit for bit, on the scanned and the unrolled layer loop, for attention and
+MLA layers (carried) and for recurrent layers (still per-layer)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,6 +88,7 @@ def _assert_same(a, b, what):
     "jamba-1.5-large-398b",     # mamba layers around one attention layer
     "xlstm-125m",               # mlstm and slstm state, replaced whole
     "deepseek-v2-236b",         # MLA latents, and an unscanned prelayer
+    "deepseek-v2-lite-ep8",     # the same with YaRN and held experts
 ])
 def test_decode_matches_write_then_attend(arch, unroll):
     cfg = get_config(arch, smoke=True)
@@ -155,9 +157,10 @@ def test_engine_greedy_tokens_match_oracle_loop():
 @pytest.mark.parametrize("arch", ["jamba-1.5-large-398b",
                                   "deepseek-v2-236b"])
 def test_engine_donates_only_carried_caches(arch):
-    """Where the layer loop reads and replaces a cache whole per layer
-    (mamba state, MLA latents, an unscanned prelayer), the engine keeps it
-    out of the donation, and donates the attention caches it carries; it
+    """Where the program reads and replaces a cache whole (mamba state, an
+    unscanned prelayer's latents), the engine keeps it out of the
+    donation, and donates the attention caches and period MLA latents it
+    carries: those arrays are gone after a step, the rest are kept. It
     serves the oracle's tokens either way."""
     cfg = get_config(arch, smoke=True)
     params = init_params(model_defs(cfg), jax.random.PRNGKey(0))
@@ -168,6 +171,7 @@ def test_engine_donates_only_carried_caches(arch):
     assert donating.add_request([5, 6, 7], max_new=3) is not None
     before = donating.cache
     carried = T.carried_layers(cfg, T.RunFlags())
+    assert any(carried)     # Jamba's attention layer, DeepSeek's latents
     donating.step()
     for c, k in zip(before["period"], carried):
         assert all(a.is_deleted() == k for a in jax.tree.leaves(c))

@@ -82,8 +82,9 @@ def test_bench_serving_smoke_keeps_slot_invariants(model):
 # serving programs at smoke size (max_batch 4, max_seq 64; jax 0.9.0).
 # InternLM2's are as they were before the engine learned the MoE counters (a
 # dense model's programs must not change when an MoE model's do);
-# DeepSeek-V2-Lite-EP8's as they were before its MLA decode moved into
-# ``models/mla.py``. A change that rightly alters them updates these.
+# DeepSeek-V2-Lite-EP8's prefill as it was before its MLA decode moved into
+# ``models/mla.py``, its decode since the period latents ride the layer
+# loop's carry. A change that rightly alters them updates these.
 SERVING_PROGRAMS = {
     "internlm2-1.8b": {
         "jit_serve_prefill":
@@ -95,7 +96,7 @@ SERVING_PROGRAMS = {
         "jit_serve_prefill":
             "a39e96a4fc827db08236ed3f7477bf963c52c77955279a821862d95ac95d2df1",
         "jit_serve_decode":
-            "0e62b6d42c014d2f2ca4bedcf79603bd29e8e22461a51e23b7c38b6011b6495a",
+            "17fe4ec8e594a0aea72a42840e18d2347a64f71175c6be8f563ed462a03b7896",
     },
 }
 

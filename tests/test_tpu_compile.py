@@ -124,6 +124,33 @@ def test_serve_decode_owns_its_cache_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < one_layer_k
 
 
+def test_mla_serve_decode_owns_its_latents_for_v5e(one_chip):
+    """ServeEngine's decode program of the DeepSeek-V2-Lite share at its
+    published widths (kv_lora_rank 512, rope key 64, YaRN, 8 held
+    experts), depth cut to 3 layers, over 16 rows of 4096 positions. The
+    program aliases the period layers' latent and rope-key stacks from
+    input to output, and its temporaries stay under one layer's latents:
+    each step writes one latent per row and layer, and copies no layer.
+
+    Fails without the mechanism: with the latents replaced whole as the
+    layer loop's per-layer input and output, nothing is donated and the
+    alias is 0."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-ep8"), n_layers=3)
+    B, S = 16, 4096
+    params, cache, tokens = _decode_args(cfg, B, S, one_chip)
+    owned, kept = split_cache(cfg, cache)
+    mem = decode_program(cfg, with_counts=True).lower(
+        params, owned, kept, tokens).compile().memory_analysis()
+    period_layers = cfg.n_layers - len(cfg.prelayers)
+    one_layer_ckv = B * S * cfg.mla.kv_lora_rank * 2
+    latents = period_layers * B * S * (
+        cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim) * 2
+    assert one_layer_ckv == 64 * 2 ** 20
+    assert _nbytes(cache["period"]) == latents
+    assert mem.alias_size_in_bytes >= latents
+    assert mem.temp_size_in_bytes < one_layer_ckv
+
+
 @pytest.mark.parametrize("arch,n_layers,smoke", [
     ("deepseek-v2-236b", 3, False),     # MLA latents, an unscanned prelayer
     ("xlstm-125m", 8, False),           # mlstm and slstm state
@@ -131,12 +158,13 @@ def test_serve_decode_owns_its_cache_for_v5e(one_chip):
 ])
 def test_serve_decode_donates_only_what_it_writes_in_place_for_v5e(
         one_chip, arch, n_layers, smoke):
-    """Caches the decode layer loop reads and replaces whole per layer stay
-    out of the donation: donated, the compiler copies each of them whole
-    in temporaries on every step (DeepSeek-V2's latents: 2.7 MB of temp
-    become 78 MB at 16 x 2048; xLSTM-125M's state: 0 become 233 MB). So the
-    engine's program aliases exactly the attention caches it carries, and
-    its temporaries are no larger than those of the same step with nothing
+    """Caches the decode program reads and replaces whole stay out of the
+    donation: donated, the compiler copies each of them whole in
+    temporaries on every step (xLSTM-125M's state: 0 B of temp become 233
+    MB at 16 x 2048). So the engine's program aliases exactly the caches
+    its layer loop carries (``carried_layers``: attention keys and values,
+    the period's MLA latents, not DeepSeek-V2's prelayer), and its
+    temporaries are no larger than those of the same step with nothing
     donated. DeepSeek-V2 keeps its published widths but 2 routed experts,
     so 3 layers fit one chip; Jamba runs at smoke widths, 16 x 2048."""
     cfg = get_config(arch, smoke=smoke)
@@ -149,9 +177,9 @@ def test_serve_decode_donates_only_what_it_writes_in_place_for_v5e(
     mem = decode_program(cfg).lower(*args).compile().memory_analysis()
     undonated = jax.jit(decode_program(cfg).__wrapped__)
     base = undonated.lower(*args).compile().memory_analysis()
-    attn_bytes = sum(_nbytes(c) for c, s in zip(cache["period"], cfg.period)
-                     if s.mixer == "attn")
-    assert mem.alias_size_in_bytes == attn_bytes
+    carried_bytes = sum(_nbytes(c) for c, k in zip(
+        cache["period"], T.carried_layers(cfg, T.RunFlags())) if k)
+    assert mem.alias_size_in_bytes == carried_bytes
     assert mem.temp_size_in_bytes <= base.temp_size_in_bytes
 
 
@@ -218,8 +246,12 @@ def test_yarn_mla_serving_reads_held_experts_in_place_for_v5e(
     held experts out of them (sliced per layer, the program does, since the
     kernel is a custom call that fuses no slice), the grouped matmuls keep
     their names and row counts (16 or 4096 rows times 6 experts per token),
-    and the temporaries are no larger than the sliced form's."""
-    _, _, programs = _docs_programs(one_chip)
+    and the temporaries hold no such copy. The prefill's sliced form keeps
+    its copy in HBM temporaries, so the stacked form's are no larger. The
+    decode's keeps it in fast memory (its temporaries read 0 B) now that
+    the latents are carried, so there the stacked form's temporaries stay
+    under one layer's held ``w_out``, the smaller of the two copies."""
+    cfg, _, programs = _docs_programs(one_chip)
     program, args = programs[name]
     c = program.lower(*args).compile()
     text = c.as_text()
@@ -229,23 +261,27 @@ def test_yarn_mla_serving_reads_held_experts_in_place_for_v5e(
     program, args = _docs_programs(one_chip)[2][name]    # traced anew
     sliced = program.lower(*args).compile()
     assert _EXPERT_COPY.search(sliced.as_text())
-    assert c.memory_analysis().temp_size_in_bytes <= \
-        sliced.memory_analysis().temp_size_in_bytes
+    temp = c.memory_analysis().temp_size_in_bytes
+    if name == "prefill":
+        assert temp <= sliced.memory_analysis().temp_size_in_bytes
+    else:
+        assert temp < cfg.moe.n_held * cfg.moe.d_ff_expert * cfg.d_model * 2
 
 
 def test_yarn_mla_decode_compiles_for_v5e(one_chip):
     """ServeEngine's decode program of the DeepSeek-V2-Lite share, YaRN on,
     with its MoE counts, at the cell's full size, 16 rows of 4096
-    positions: it compiles, donates nothing (MLA latents are replaced
-    whole), its temporaries stay under a tenth of the latent cache it
-    rewrites, and no op copies a layer's held experts."""
+    positions: it compiles, aliases the 26 period layers' latents it
+    writes in place (the prelayer's it replaces whole), its temporaries
+    stay under a tenth of the latent cache, and no op copies a layer's
+    held experts."""
     cfg, cache, programs = _docs_programs(one_chip)
     program, args = programs["decode"]
     c = program.lower(*args).compile()
     mem = c.memory_analysis()
     latents = _nbytes(cache["period"]) + _nbytes(cache["prelayers"])
     assert latents == cfg.n_layers * 16 * 4096 * (512 + 64) * 2
-    assert mem.alias_size_in_bytes == 0
+    assert mem.alias_size_in_bytes == _nbytes(cache["period"])
     assert mem.temp_size_in_bytes < latents / 10
     assert "ragged-dot" in c.as_text()
     assert not _EXPERT_COPY.search(c.as_text())
