@@ -7,14 +7,15 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from repro.configs.base import (LayerSpec, MLAConfig, MambaConfig, ModelConfig,
-                                MoEConfig, ShapeConfig, SHAPES, XLSTMConfig,
-                                YarnConfig, shape_applicable)
+from repro.configs.base import (LayerSpec, MLAConfig, Mamba2Config,
+                                MambaConfig, ModelConfig, MoEConfig,
+                                ShapeConfig, SHAPES, XLSTMConfig, YarnConfig,
+                                shape_applicable)
 
 from repro.configs import (starcoder2_15b, internlm2_1_8b, llama3_405b,
                            command_r_plus_104b, internvl2_2b, xlstm_125m,
                            qwen2_moe_a2_7b, deepseek_v2_236b,
-                           deepseek_v2_lite,
+                           deepseek_v2_lite, granite_4_0_h_small,
                            jamba_1_5_large_398b, musicgen_medium, tacc_100m)
 
 _MODULES = {
@@ -29,6 +30,8 @@ _MODULES = {
     "deepseek-v2-lite": deepseek_v2_lite,
     "deepseek-v2-lite-ep8": deepseek_v2_lite.EP8,
     "jamba-1.5-large-398b": jamba_1_5_large_398b,
+    "granite-4.0-h-small": granite_4_0_h_small,
+    "granite-4.0-h-small-ep8": granite_4_0_h_small.EP8,
     "musicgen-medium": musicgen_medium,
     "tacc-100m": tacc_100m,
 }

@@ -65,6 +65,25 @@ class MambaConfig:
 
 
 @dataclass(frozen=True)
+class Mamba2Config:
+    """Mamba-2's SSD mixer (arXiv:2405.21060): ``n_heads`` heads of
+    ``head_dim`` channels, each with one scalar decay, sharing ``n_groups``
+    groups of B and C of ``d_state``; a causal depthwise conv of ``d_conv``
+    over x, B and C together (with bias); prefill in chunks of
+    ``chunk_size`` positions."""
+    n_heads: int = 128
+    head_dim: int = 64
+    d_state: int = 128
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk_size: int = 256
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+
+@dataclass(frozen=True)
 class XLSTMConfig:
     n_heads: int = 4
     expand: int = 2                # mLSTM up-projection factor
@@ -74,7 +93,7 @@ class XLSTMConfig:
 @dataclass(frozen=True)
 class LayerSpec:
     """One sublayer position inside the repeating period."""
-    mixer: str                     # attn | mla | mamba | mlstm | slstm
+    mixer: str                     # attn | mla | mamba | mamba2 | mlstm | slstm
     ffn: str = "dense"             # dense | moe | none
     parallel: bool = False         # parallel attention+FFN (Cohere-style)
 
@@ -106,10 +125,18 @@ class ModelConfig:
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
     embedding_multiplier: float = 1.0
+    # muP multipliers (Granite): each sublayer's output is scaled by
+    # ``residual_multiplier`` before its residual add, logits are divided by
+    # ``logits_scaling``, and attention scores are scaled by ``attn_scale``
+    # (0 -> 1/sqrt(head_dim)). Each applies only where it is set.
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attn_scale: float = 0.0
     # sub-configs
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     mamba: Optional[MambaConfig] = None
+    mamba2: Optional[Mamba2Config] = None
     xlstm: Optional[XLSTMConfig] = None
     # modality frontend stub: tokens | embeds (audio frames) | tokens+vision
     input_mode: str = "tokens"
@@ -158,11 +185,16 @@ class ModelConfig:
         mamba = self.mamba
         if mamba is not None:
             mamba = replace(mamba, d_state=8, d_conv=4, expand=2, dt_rank=8)
+        mamba2 = self.mamba2
+        if mamba2 is not None:
+            mamba2 = replace(mamba2, n_heads=4, head_dim=16, d_state=8,
+                             chunk_size=16)
         defaults = dict(
             name=self.name + "-smoke", n_layers=n_layers, d_model=64,
             n_heads=4, n_kv_heads=max(1, min(self.n_kv_heads, 2)),
             head_dim=16, d_ff=128 if self.d_ff else 0, vocab_size=256,
-            moe=moe, mla=mla, mamba=mamba, xlstm=self.xlstm,
+            moe=moe, mla=mla, mamba=mamba, mamba2=mamba2,
+            xlstm=self.xlstm,
             vision_tokens=8 if self.vision_tokens else 0,
             attn_chunk=32, max_attn_chunks=4,
         )

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,9 +78,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, block_q: int = 512,
-                        block_k: int = 512, interpret: bool = False
-                        ) -> jax.Array:
+                        block_k: int = 512, interpret: bool = False,
+                        scale: Optional[float] = None) -> jax.Array:
     """q, k, v: (B, H, S, D) with matching head counts. Returns (B, H, S, D).
+    The scores are scaled by ``scale`` (default ``D ** -0.5``).
 
     S must divide by the chosen block sizes (ops.py pads otherwise).
     """
@@ -92,7 +94,8 @@ def flash_attention_tpu(q: jax.Array, k: jax.Array, v: jax.Array, *,
         block_k //= 2
     n_q = S // block_q
     n_k = S // block_k
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
 
     kernel = functools.partial(_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, n_k=n_k)

@@ -7,6 +7,7 @@ calls these through RunFlags.backend.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,9 +19,9 @@ from repro.kernels.rmsnorm import rmsnorm_tpu, rmsnorm_residual_tpu
 
 
 @partial(jax.jit, static_argnames=("causal", "interpret", "block_q",
-                                   "block_k", "pad_to"))
+                                   "block_k", "pad_to", "scale"))
 def _flash_bhsd(q, k, v, causal: bool, interpret: bool, block_q: int,
-                block_k: int, pad_to: int):
+                block_k: int, pad_to: int, scale: Optional[float] = None):
     # pad_to > S only when the autotune table chose non-dividing blocks for
     # a causal call; end-padding the keys is exact there (padded rows sit
     # strictly above the diagonal of every real query row).  The no-entry
@@ -31,14 +32,16 @@ def _flash_bhsd(q, k, v, causal: bool, interpret: bool, block_q: int,
         cfg = ((0, 0), (0, 0), (0, pad_to - S), (0, 0))
         q, k, v = jnp.pad(q, cfg), jnp.pad(k, cfg), jnp.pad(v, cfg)
     o = flash_attention_tpu(q, k, v, causal=causal, block_q=block_q,
-                            block_k=block_k, interpret=interpret)
+                            block_k=block_k, interpret=interpret, scale=scale)
     return o[:, :, :S] if pad_to > S else o
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
-                    interpret: bool = False) -> jax.Array:
-    """q,k,v: (B,S,H,D) (kv pre-repeated to H heads). Returns (B,S,H,D).
+                    interpret: bool = False,
+                    scale: Optional[float] = None) -> jax.Array:
+    """q,k,v: (B,S,H,D) (kv pre-repeated to H heads). Returns (B,S,H,D);
+    the scores are scaled by ``scale`` (default ``D ** -0.5``).
 
     Dense causal/full attention only: the kernel has no ragged-``lengths``
     mask, so ``models.attention.self_attention`` sends ragged calls to the
@@ -48,7 +51,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     bq, bk, pad_to, _ = autotune.plan_flash(qt.shape, qt.dtype, causal=causal)
-    o = _flash_bhsd(qt, kt, vt, causal, interpret, bq, bk, pad_to)
+    o = _flash_bhsd(qt, kt, vt, causal, interpret, bq, bk, pad_to, scale)
     return o.transpose(0, 2, 1, 3)
 
 

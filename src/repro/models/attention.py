@@ -196,11 +196,13 @@ def self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
     if backend in ("pallas", "interpret") and lengths is None:
         from repro.kernels import ops as kops
         o = kops.flash_attention(q, kf, vf, causal=True,
-                                 interpret=(backend == "interpret"))
+                                 interpret=(backend == "interpret"),
+                                 scale=cfg.attn_scale or None)
     else:
         o = flash_attention_xla(q, kf, vf, causal=True, lengths=lengths,
                                 chunk=cfg.attn_chunk,
-                                max_chunks=cfg.max_attn_chunks, unroll=unroll)
+                                max_chunks=cfg.max_attn_chunks, unroll=unroll,
+                                scale=cfg.attn_scale or None)
     return output_proj(cfg, p, o), (k, v)
 
 
@@ -210,15 +212,18 @@ def self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
 # ---------------------------------------------------------------------------
 
 def decode_attention_ref(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                         lengths: jax.Array) -> jax.Array:
+                         lengths: jax.Array, scale: Optional[float] = None
+                         ) -> jax.Array:
     """q: (B,H,HD); caches: (B,S,KV,HD); lengths (B,) = #valid positions
-    (including the token just written). Grouped GQA, full softmax."""
+    (including the token just written). Grouped GQA, full softmax; the
+    scores are scaled by ``scale`` (default ``HD ** -0.5``)."""
     B, H, HD = q.shape
     KV = k_cache.shape[2]
     G = H // KV
     qg = q.reshape(B, KV, G, HD)
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
-                   preferred_element_type=jnp.float32) / math.sqrt(HD)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(HD) if scale is None else s * scale
     kpos = jnp.arange(k_cache.shape[1])
     s = jnp.where(kpos[None, None, None, :] < lengths[:, None, None, None],
                   s, NEG_INF)
@@ -274,17 +279,18 @@ def decode_self_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
     writes and attends instead."""
     q, k, v = project_qkv(cfg, p, x, lengths[:, None])
     q1, k1, v1 = q[:, 0], k[:, 0], v[:, 0]
+    scale = cfg.attn_scale or None
     seq_axes = live_seq_axes(seq_axes)
     if seq_axes:
         o, kc, vc = sharded_decode_attention(
             q1, cache["k"], cache["v"], k1, v1, lengths, seq_axes=seq_axes,
-            batch_axes=batch_axes)
+            batch_axes=batch_axes, scale=scale)
     elif layer is None:
         kc, vc = write_kv_cache(cache["k"], cache["v"], k1, v1, lengths)
-        o = decode_attention_ref(q1, kc, vc, lengths + 1)
+        o = decode_attention_ref(q1, kc, vc, lengths + 1, scale)
     else:
         kc = write_kv_token(cache["k"], k1, lengths, layer)
         vc = write_kv_token(cache["v"], v1, lengths, layer)
-        o = decode_attention_ref(q1, kc[layer], vc[layer], lengths + 1)
+        o = decode_attention_ref(q1, kc[layer], vc[layer], lengths + 1, scale)
     y = output_proj(cfg, p, o[:, None])
     return y, {"k": kc, "v": vc}

@@ -194,4 +194,6 @@ def unembed(cfg: ModelConfig, p: Dict, x: jax.Array) -> jax.Array:
     if cfg.logit_softcap:
         c = cfg.logit_softcap
         logits = jnp.tanh(logits / c) * c
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return logits

@@ -32,7 +32,7 @@ AxisName = Union[str, Tuple[str, ...], None]
 class ParamDef:
     shape: Tuple[int, ...]
     axes: Tuple[Optional[str], ...]      # logical axis name per dim (None = replicated)
-    init: str = "normal"                 # normal | zeros | ones | scaled | ssm_a | embed
+    init: str = "normal"                 # normal | zeros | ones | scaled | ssm_a | ssm_dt | embed
     dtype: Any = jnp.float32
     scale: float = 1.0                   # stddev multiplier for normal/scaled
 
@@ -137,6 +137,11 @@ def init_one(d: ParamDef, key: jax.Array) -> jax.Array:
         d_state = d.shape[-1]
         a = jnp.tile(jnp.arange(1, d_state + 1, dtype=jnp.float32), d.shape[:-1] + (1,))
         return jnp.log(a).astype(d.dtype)
+    if d.init == "ssm_dt":
+        # Mamba-2 dt bias: softplus(bias) log-uniform in [0.001, 0.1]
+        u = jax.random.uniform(key, d.shape, jnp.float32)
+        dt = jnp.exp(u * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(d.dtype)
     if d.init == "embed":
         return (jax.random.normal(key, d.shape, jnp.float32) * d.scale).astype(d.dtype)
     if d.init in ("normal", "scaled"):

@@ -22,6 +22,7 @@ from repro.models import attention as A
 from repro.models import mla as MLA
 from repro.models import moe as MOE
 from repro.models import mamba as MB
+from repro.models import mamba2 as MB2
 from repro.models import xlstm as XL
 
 
@@ -65,6 +66,7 @@ _MIXER_DEFS = {
     "attn": A.attn_defs,
     "mla": MLA.mla_defs,
     "mamba": MB.mamba_defs,
+    "mamba2": MB2.mamba2_defs,
     "mlstm": XL.mlstm_defs,
     "slstm": XL.slstm_defs,
 }
@@ -113,6 +115,8 @@ def layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, s_max: int):
                 "kr": jnp.zeros((batch, s_max, m.qk_rope_head_dim), jnp.bfloat16)}
     if spec.mixer == "mamba":
         return MB.mamba_init_cache(cfg, batch)
+    if spec.mixer == "mamba2":
+        return MB2.mamba2_init_cache(cfg, batch)
     if spec.mixer == "mlstm":
         return XL.mlstm_init_cache(cfg, batch)
     if spec.mixer == "slstm":
@@ -159,6 +163,9 @@ def _apply_mixer_seq(cfg, spec, p, x, positions, lengths, flags, want_cache):
                 jnp.full((x.shape[0],), x.shape[1], jnp.int32)
             cache = MB.mamba_prefill_cache(cfg, p, x, lens)
         return y, cache
+    if spec.mixer == "mamba2":
+        y, cache = MB2.mamba2_mixer(cfg, p, x, lengths)
+        return y, cache if want_cache else None
     if spec.mixer in ("mlstm", "slstm"):
         mix = XL.mlstm_mixer if spec.mixer == "mlstm" else XL.slstm_mixer
         y = mix(cfg, p, x)
@@ -185,18 +192,27 @@ def apply_layer_seq(cfg: ModelConfig, spec: LayerSpec, p: Dict, x: jax.Array,
     valid = None
     if spec.ffn == "moe" and lengths is not None:
         valid = positions < lengths[:, None]
+    y_mix = _residual(cfg, y_mix)
     if spec.parallel and spec.ffn != "none":
         y_ffn, aux, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid,
                                         expert_layer)
-        x = x + y_mix + y_ffn
+        x = x + y_mix + _residual(cfg, y_ffn)
         return x, cache, aux, counts
     x = x + y_mix
     if spec.ffn != "none":
         h = L.apply_norm(cfg, p["ffn_norm"], x)
         y_ffn, aux, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid,
                                         expert_layer)
-        x = x + y_ffn
+        x = x + _residual(cfg, y_ffn)
     return x, cache, aux, counts
+
+
+def _residual(cfg: ModelConfig, y: jax.Array) -> jax.Array:
+    """A sublayer's output as its residual add takes it: scaled by the
+    configuration's ``residual_multiplier`` where that is set."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * jnp.asarray(cfg.residual_multiplier, y.dtype)
 
 
 def _apply_ffn(cfg, spec, p, h, flags, valid=None, expert_layer=None):
@@ -234,6 +250,8 @@ def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
             seq_axes=flags.decode_seq_axes, batch_axes=flags.token_axes)
     elif spec.mixer == "mamba":
         y_mix, new_cache = MB.mamba_decode(cfg, p["mixer"], h, cache)
+    elif spec.mixer == "mamba2":
+        y_mix, new_cache = MB2.mamba2_decode(cfg, p["mixer"], h, cache)
     elif spec.mixer == "mlstm":
         y_mix, new_cache = XL.mlstm_decode(cfg, p["mixer"], h, cache)
     elif spec.mixer == "slstm":
@@ -242,17 +260,18 @@ def apply_layer_decode(cfg: ModelConfig, spec: LayerSpec, p: Dict,
         raise ValueError(spec.mixer)
     valid = (lengths > 0)[:, None] if spec.ffn == "moe" else None
     counts = None
+    y_mix = _residual(cfg, y_mix)
     if spec.parallel and spec.ffn != "none":
         y_ffn, _, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags, valid,
                                       expert_layer)
-        x = x + y_mix + y_ffn
+        x = x + y_mix + _residual(cfg, y_ffn)
     else:
         x = x + y_mix
         if spec.ffn != "none":
             h = L.apply_norm(cfg, p["ffn_norm"], x)
             y_ffn, _, counts = _apply_ffn(cfg, spec, p["ffn"], h, flags,
                                           valid, expert_layer)
-            x = x + y_ffn
+            x = x + _residual(cfg, y_ffn)
     return x, new_cache, counts
 
 

@@ -20,7 +20,7 @@ module imports nothing from ``repro.models``.
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,9 +81,11 @@ def sharded_decode_attention(q: jax.Array, k_cache: jax.Array,
                              v_new: jax.Array, lengths: jax.Array, *,
                              seq_axes: Tuple[str, ...] = ("model",),
                              batch_axes: Tuple[str, ...] = ("data",),
-                             mesh=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                             mesh=None, scale: Optional[float] = None
+                             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """q: (B, H, HD); caches: (B, S, KV, HD); k_new/v_new: (B, KV, HD);
     lengths: (B,) tokens already cached (new token appended, attends to self).
+    ``scale`` multiplies the scores (default ``HD ** -0.5``).
 
     Returns (o (B,H,HD), k_cache', v_cache'). ``seq_axes`` are live
     (``live_seq_axes``).
@@ -94,7 +96,8 @@ def sharded_decode_attention(q: jax.Array, k_cache: jax.Array,
     B, H, HD = q.shape
     KV = k_cache.shape[2]
     G = H // KV
-    scale = 1.0 / math.sqrt(HD)
+    if scale is None:
+        scale = 1.0 / math.sqrt(HD)
     S = k_cache.shape[1]
     n_shards = math.prod(axis_sizes[a] for a in seq_axes)
     S_loc = S // n_shards

@@ -11,7 +11,7 @@ Decode layout (TPU flash-decoding):
   - activations replicated within a pod (tiny at S=1);
   - KV cache sharded along *sequence* over ("data","model") — and over "pod"
     too when the batch cannot split across pods (long_500k, batch=1);
-  - recurrent state (mamba/xlstm): d_inner over ``model``.
+  - recurrent state (mamba/xlstm): d_inner over ``model`` (Mamba-2: heads).
 """
 from __future__ import annotations
 
@@ -41,6 +41,9 @@ def layer_cache_specs(cfg: ModelConfig, spec: LayerSpec,
     if spec.mixer == "mamba":
         return {"conv": P(*pre, b, None, "model"),
                 "ssm": P(*pre, b, "model", None)}
+    if spec.mixer == "mamba2":
+        return {"conv": P(*pre, b, None, "model"),
+                "ssm": P(*pre, b, "model", None, None)}
     if spec.mixer == "mlstm":
         return {"C": P(*pre, b, None, None, None),
                 "n": P(*pre, b, None, None),

@@ -19,6 +19,9 @@ NOMINAL = {
     # one chip's share of 8-chip expert parallelism: 8 of 64 experts held
     "deepseek-v2-lite-ep8": 3.1e9,
     "jamba-1.5-large-398b": 398e9,
+    "granite-4.0-h-small": 32.21e9,
+    # one chip's share of 16: layers 0-19 and 9 of 72 experts held
+    "granite-4.0-h-small-ep8": 4.418e9,
     "musicgen-medium": 1.5e9,
 }
 
@@ -74,3 +77,17 @@ def test_exact_dims_spotcheck():
     assert sum(1 for s in c.period if s.mixer == "attn") == 1
     assert sum(1 for s in c.period if s.ffn == "moe") == 4
     assert len(c.period) == 8 and c.n_periods == 9
+
+
+def test_granite_counts_its_published_size():
+    """Granite-4.0-H-Small is published as 32B: the full model counts
+    32.21e9 parameters (within 0.5 %), and the share this chip serves,
+    layers 0-19 with 9 of each layer's 72 experts, 4.418e9."""
+    full = param_count(model_defs(get_config("granite-4.0-h-small")))
+    assert abs(full / 32.21e9 - 1) < 0.005, full
+    share = param_count(model_defs(get_config("granite-4.0-h-small-ep8")))
+    assert round(share / 1e6) == 4418, share
+    c = get_config("granite-4.0-h-small")
+    assert [s.mixer for s in c.period] == ["mamba2"] * 5 + ["attn"] + \
+        ["mamba2"] * 4
+    assert all(s.ffn == "moe" for s in c.period) and c.n_periods == 4

@@ -54,6 +54,41 @@ def test_ops_dispatcher_bshd_layout():
                                np.asarray(r, np.float32), atol=3e-2)
 
 
+def test_ops_dispatcher_takes_a_softmax_scale():
+    """A set scale (Granite-4.0-H's 1/head_dim) replaces 1/sqrt(head_dim):
+    the same as scaling q by scale * sqrt(head_dim) before the default."""
+    B, S, H, D = 1, 256, 2, 128
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k, v = (jax.random.normal(kk, (B, S, H, D), jnp.float32)
+               for kk in keys)
+    o = ops.flash_attention(q, k, v, causal=True, interpret=True,
+                            scale=1 / D)
+    r = ref.attention_ref(*(t.transpose(0, 2, 1, 3)
+                            for t in (q * (D ** 0.5 / D), k, v)),
+                          causal=True).transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=3e-5)
+
+
+def test_model_attention_scale_matches_on_both_backends():
+    """A configuration with its own softmax scale takes the Pallas kernel
+    too, and it matches the XLA path's scaled attention (float32: the two
+    differ only in the order of their sums)."""
+    from dataclasses import replace
+    from repro.configs import get_config
+    from repro.models import model_defs, init_params
+    from repro.models.attention import self_attention
+    cfg = replace(get_config("granite-4.0-h-small", smoke=True),
+                  dtype="float32")
+    assert cfg.attn_scale and cfg.period[1].mixer == "attn"
+    params = init_params(model_defs(cfg), jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda a: a[0], params["period"][1]["mixer"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(64), (2, 64))
+    ox, _ = self_attention(cfg, p, x, pos, backend="xla")
+    op, _ = self_attention(cfg, p, x, pos, backend="interpret")
+    np.testing.assert_allclose(np.asarray(op), np.asarray(ox), atol=1e-5)
+
+
 @pytest.mark.parametrize("N,D", [(64, 256), (256, 512), (8, 128), (100, 384)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rmsnorm_interpret_vs_ref(N, D, dtype):

@@ -84,7 +84,9 @@ def test_bench_serving_smoke_keeps_slot_invariants(model):
 # dense model's programs must not change when an MoE model's do);
 # DeepSeek-V2-Lite-EP8's prefill as it was before its MLA decode moved into
 # ``models/mla.py``, its decode since the period latents ride the layer
-# loop's carry. A change that rightly alters them updates these.
+# loop's carry. Granite-4.0-H-Small-EP8's as it was added, Mamba-2 state read
+# and replaced whole beside the two attention layers' carried keys and
+# values. A change that rightly alters them updates these.
 SERVING_PROGRAMS = {
     "internlm2-1.8b": {
         "jit_serve_prefill":
@@ -97,6 +99,12 @@ SERVING_PROGRAMS = {
             "a39e96a4fc827db08236ed3f7477bf963c52c77955279a821862d95ac95d2df1",
         "jit_serve_decode":
             "17fe4ec8e594a0aea72a42840e18d2347a64f71175c6be8f563ed462a03b7896",
+    },
+    "granite-4.0-h-small-ep8": {
+        "jit_serve_prefill":
+            "6fe798ce17df1e428eb61f3d6377f1d8f4702babc0812d2e594529ea8a072a1b",
+        "jit_serve_decode":
+            "56693f007dc37b9da386d4e97cb8af9dc79e78795678169eb5875e0f907a5b1f",
     },
 }
 

@@ -285,3 +285,36 @@ def test_yarn_mla_decode_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < latents / 10
     assert "ragged-dot" in c.as_text()
     assert not _EXPERT_COPY.search(c.as_text())
+
+
+def test_granite_share_serving_fits_one_v5e(one_chip):
+    """ServeEngine's decode and prefill programs of the Granite-4.0-H-Small
+    share at the cell's full size (20 layers: 18 Mamba-2 and 2 attention,
+    every one with 9 of 72 experts held; 16 rows of 4096 positions) fit one
+    v5e's 16 GiB with the weights, the cache and the step's outputs, and the
+    decode aliases exactly the two attention layers' key and value stacks
+    it writes in place: the Mamba-2 state (1.22 GB) is read and replaced
+    whole, so it stays out of the donation."""
+    cfg = get_config("granite-4.0-h-small-ep8")
+    params, cache, tokens = _decode_args(cfg, 16, 4096, one_chip)
+    owned, kept = split_cache(cfg, cache)
+    kv = 2 * 2 * 16 * 4096 * cfg.n_kv_heads * cfg.head_dim * 2
+    state = 18 * 16 * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
+    assert _nbytes(owned) == kv and _nbytes(cache["period"]) == kv + state
+    c = decode_program(cfg, with_counts=True).lower(
+        params, owned, kept, tokens).compile()
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes == kv
+    assert "ragged-dot" in c.as_text()
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    pre = jax.jit(lambda p, b, n: prefill(cfg, p, b, n, with_counts=True)
+                  ).lower(params, {"tokens": spec((1, 4096))}, spec((1,))
+                          ).compile().memory_analysis()
+    # the engine keeps its batch cache beside either program (the decode's
+    # arguments hold it already: counted twice there, a bound from above)
+    for m in (mem, pre):
+        peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert peak + _nbytes(cache) < 16 * 2 ** 30
